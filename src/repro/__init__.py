@@ -1,10 +1,1 @@
-"""repro: SystolicAttention reproduction + the jax_pallas scale-out stack.
-
-Importing the package installs the JAX forward-compat shims (see
-``repro.compat``) so every module can be written against the modern mesh
-API regardless of the jaxlib baked into the host image.
-"""
-
-from . import compat as _compat
-
-_compat.ensure()
+"""repro: SystolicAttention reproduction + the jax_pallas scale-out stack."""

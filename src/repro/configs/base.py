@@ -63,7 +63,10 @@ class ModelConfig:
     attn_every: int = 0  # zamba2: shared attention block every N ssm layers
     # Execution knobs
     parallelism: str = "tp"  # tp (Megatron TP+DP+SP) | dp_only (pure DP+ZeRO)
-    attention_impl: str = "systolic"  # systolic | pallas | naive
+    # None: by platform (models.attention._impl_attention) — the compiled
+    # Pallas kernel on TPU, the systolic jnp scan elsewhere.  Set to
+    # "systolic" | "pallas" | "naive" to force one (e.g. an f32 reference).
+    attention_impl: Optional[str] = None
     exp2_impl: str = "exact"  # exact | pwl (paper-faithful numerics)
     attn_block_q: int = 128
     attn_block_k: int = 128

@@ -32,7 +32,6 @@ __all__ = [
     "DEFAULT_SEGMENTS",
     "segment_table",
     "pwl_coeffs",
-    "packed_coeff_table",
     "pwl_exp2",
     "pwl_exp",
     "exp2_reference",
@@ -56,46 +55,24 @@ def segment_table(num_segments: int = DEFAULT_SEGMENTS) -> tuple[np.ndarray, np.
     return slope.astype(np.float32), intercept.astype(np.float32)
 
 
-def pwl_coeffs(
-    idx: jax.Array,
-    num_segments: int,
-    tables: "tuple[jax.Array, jax.Array] | None" = None,
-) -> tuple[jax.Array, jax.Array]:
-    """(slope, intercept) per element from the segment index, vectorized.
+def pwl_coeffs(idx: jax.Array, num_segments: int) -> tuple[jax.Array, jax.Array]:
+    """(slope, intercept) per element from the segment index.
 
-    A single one-hot contraction over a trailing [K] dim: one compare plus
-    two multiply-accumulate reductions, instead of a K-deep jnp.where
-    chain.  Bit-identical to selecting from the table — exactly one one-hot
-    term is nonzero, and its product with the fp32 coefficient is exact.
-    Uses broadcasted_iota (TPU needs >=2D iota) so it lowers inside Pallas
-    kernel bodies, where vector gathers don't.
-
-    ``tables`` supplies the [K] slope/intercept vectors when they are
-    already loaded (Pallas kernels must receive them as inputs — captured
-    constant arrays are rejected); defaults to the module table.
+    A select chain over the K table entries, each a Python scalar.  Pallas
+    TPU kernels take scalars as literals (captured constant arrays are
+    rejected, and vector gathers do not lower), and the chain keeps every
+    intermediate at the shape of ``idx``: a one-hot ``[..., K]`` operand
+    pads its K lanes to 128 and overflows VMEM.  Bit-identical to indexing
+    the table — each element selects one exact fp32 coefficient.
     """
-    if tables is None:
-        slope_t, intercept_t = segment_table(num_segments)
-        slope_t, intercept_t = jnp.asarray(slope_t), jnp.asarray(intercept_t)
-    else:
-        slope_t, intercept_t = tables
-    seg = jax.lax.broadcasted_iota(
-        jnp.int32, (*idx.shape, num_segments), idx.ndim
-    )
-    onehot = (idx[..., None] == seg).astype(jnp.float32)
-    slope = jnp.sum(onehot * slope_t, axis=-1)
-    intercept = jnp.sum(onehot * intercept_t, axis=-1)
-    return slope, intercept
-
-
-def packed_coeff_table(num_segments: int, lanes: int = 128) -> np.ndarray:
-    """Slope/intercept packed as one lane-aligned [2, lanes] fp32 array —
-    the form the Pallas kernels take as an input operand."""
     slope_t, intercept_t = segment_table(num_segments)
-    packed = np.zeros((2, max(lanes, num_segments)), np.float32)
-    packed[0, :num_segments] = slope_t
-    packed[1, :num_segments] = intercept_t
-    return packed
+    slope = jnp.full(idx.shape, float(slope_t[0]), jnp.float32)
+    intercept = jnp.full(idx.shape, float(intercept_t[0]), jnp.float32)
+    for k in range(1, num_segments):
+        hit = idx == k
+        slope = jnp.where(hit, float(slope_t[k]), slope)
+        intercept = jnp.where(hit, float(intercept_t[k]), intercept)
+    return slope, intercept
 
 
 def _split_int_frac(x: jax.Array) -> tuple[jax.Array, jax.Array]:

@@ -21,10 +21,7 @@ and MoE expert-weight ZeRO-3), "pod" carries either pipeline stages
 ``sharding``'s batch rules).
 """
 
-# NOTE: importing any repro.* module runs repro/__init__.py first, which
-# installs the JAX compat shims (repro.compat.ensure) these modules rely on.
-
-from .collectives import constrain  # noqa: F401
+from .collectives import constrain, mesh_context  # noqa: F401
 from .elastic import RescalePlan, apply_rescale, rescale_plan  # noqa: F401
 from .fault import (  # noqa: F401
     PreemptionHandler,

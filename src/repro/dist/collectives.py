@@ -10,7 +10,9 @@ same model code runs unmodified on a laptop CPU and on a multi-pod slice.
 
 from __future__ import annotations
 
-from typing import Optional, Sequence, Union
+import contextlib
+import math
+from typing import Sequence, Union
 
 import jax
 from jax.sharding import PartitionSpec as P
@@ -20,9 +22,12 @@ AxisSpec = Union[None, str, Sequence[str]]
 
 def _ambient_mesh():
     mesh = jax.sharding.get_abstract_mesh()
-    if mesh is None or getattr(mesh, "empty", False):
-        return None
-    return mesh
+    return None if mesh.empty else mesh
+
+
+def mesh_context(mesh):
+    """``jax.set_mesh(mesh)``; a null context when ``mesh`` is None."""
+    return jax.set_mesh(mesh) if mesh is not None else contextlib.nullcontext()
 
 
 def _ambient_axis_names() -> tuple[str, ...]:
@@ -64,6 +69,31 @@ def constrain(x: jax.Array, *spec: AxisSpec) -> jax.Array:
     if all(e is None for e in entries):
         return x
     return jax.lax.with_sharding_constraint(x, P(*entries))
+
+
+def map_heads(f, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
+    """Run attention ``f`` on each device's block of ``[B, S, H, d]`` q/k/v.
+
+    GSPMD cannot partition a Mosaic (Pallas TPU) kernel, so under an
+    ambient mesh the kernel runs inside ``shard_map``: batch over the data
+    axes, heads over "model" (the layout the column-parallel QKV
+    projections produce).  Heads stay whole unless "model" divides both the
+    query and the KV head counts, so every shard keeps its GQA groups.
+    Identity wrapper without a mesh.
+    """
+    mesh = _ambient_mesh()
+    if mesh is None:
+        return f(q, k, v)
+    heads = math.gcd(q.shape[2], k.shape[2])
+    spec = P(
+        _resolve_entry(("pod", "data"), q.shape[0], mesh),
+        None,
+        _resolve_entry("model", heads, mesh),
+        None,
+    )
+    return jax.shard_map(
+        f, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
+    )(q, k, v)
 
 
 def psum_mean(x: jax.Array, axis_name: str) -> jax.Array:

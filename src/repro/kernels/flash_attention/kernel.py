@@ -33,16 +33,15 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.pwl_exp2 import LOG2_E, packed_coeff_table, pwl_coeffs
+from repro.core.pwl_exp2 import LOG2_E, pwl_coeffs
 
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+LANES = 128  # TPU vector lane width: row statistics are stored lane-broadcast
 
 
-def _exp2_inline(
-    x: jax.Array, exp2_impl: str, num_segments: int, tables=None
-) -> jax.Array:
+def _exp2_inline(x: jax.Array, exp2_impl: str, num_segments: int) -> jax.Array:
     """exp2 on a VMEM-resident fp32 tile; 'pwl' follows §3.3 bit-for-bit."""
     if exp2_impl == "exact":
         return jnp.exp2(x)
@@ -51,10 +50,9 @@ def _exp2_inline(
     idx = jnp.clip(
         jnp.floor((x_f + 1.0) * num_segments).astype(jnp.int32), 0, num_segments - 1
     )
-    # Vectorized one-hot segment select (bit-identical to an unrolled
-    # where-chain) — one compare + two MAC reductions on the VPU; mirrors
-    # the hardware streaming slope/intercept into the PE rows.
-    slope, intercept = pwl_coeffs(idx, num_segments, tables)
+    # Segment select on the VPU; mirrors the hardware streaming
+    # slope/intercept into the PE rows.
+    slope, intercept = pwl_coeffs(idx, num_segments)
     frac = slope * x_f + intercept  # the PE-MAC step
     e = jnp.clip(x_i, -150.0, 127.0).astype(jnp.int32)
     out = jnp.ldexp(frac, e)
@@ -65,7 +63,7 @@ def _fwd_kernel(
     q_ref,  # [1, block_q, d]
     k_ref,  # [1, block_k, d]
     v_ref,  # [1, block_k, d]
-    *refs,  # [coeff_ref [2, lanes] if pwl], o_ref, [lse_ref], scratch
+    *refs,  # o_ref, [lse_ref], m_scr, l_scr, acc_scr
     num_k_blocks: int,
     block_q: int,
     block_k: int,
@@ -77,13 +75,6 @@ def _fwd_kernel(
     seq_k: int,
     with_lse: bool,
 ):
-    tables = None
-    if exp2_impl == "pwl":
-        coeff_ref, *refs = refs
-        tables = (
-            coeff_ref[0, :num_segments],
-            coeff_ref[1, :num_segments],
-        )
     if with_lse:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
     else:
@@ -105,7 +96,8 @@ def _fwd_kernel(
     q = q_ref[0].astype(jnp.float32)  # [bq, d]
     k = k_ref[0].astype(jnp.float32)  # [bk, d]
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )  # [bq, bk] — unscaled S, as in Algorithm 1 line 6
 
     cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -122,12 +114,15 @@ def _fwd_kernel(
     old_m = m_scr[...]
     local_m = jnp.max(s, axis=-1)
     new_m = jnp.maximum(local_m, old_m)                      # line 8
-    b = _exp2_inline(c * (old_m - new_m), exp2_impl, num_segments, tables)  # line 10
-    p = _exp2_inline(c * (s - new_m[:, None]), exp2_impl, num_segments, tables)  # line 12
+    b = _exp2_inline(c * (old_m - new_m), exp2_impl, num_segments)  # line 10
+    p = _exp2_inline(c * (s - new_m[:, None]), exp2_impl, num_segments)  # line 12
     l_scr[...] = l_scr[...] * b + jnp.sum(p, axis=-1)        # lines 13-14
     v = v_ref[0].astype(jnp.float32)
+    # Both products run at contract precision fp32: P stays fp32, as in
+    # the decode einsum that must agree with this kernel.
     local_o = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
     )
     acc_scr[...] = acc_scr[...] * b[:, None] + local_o       # line 16
     m_scr[...] = new_m
@@ -139,8 +134,10 @@ def _fwd_kernel(
         o_ref[0, :, :] = (acc_scr[...] / safe_l[:, None]).astype(o_ref.dtype)
         if with_lse:
             # Base-2 LSE with the scale folded in: P = exp2(c*S - LSE) is
-            # the *normalized* probability the backward recomputes.
-            lse_ref[0, :] = c * m_scr[...] + jnp.log2(safe_l)
+            # the *normalized* probability the backward recomputes.  Stored
+            # lane-broadcast: a [block_q] row block is not (8, 128)-tiled.
+            lse = c * m_scr[...] + jnp.log2(safe_l)
+            lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
 
 
 def flash_attention_fwd(
@@ -204,15 +201,6 @@ def flash_attention_fwd(
         pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
         pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
     ]
-    operands = [qh, kh, vh]
-    if exp2_impl == "pwl":
-        # PWL slope/intercept table as a (replicated, lane-aligned) operand:
-        # Pallas kernels reject captured constant arrays.
-        coeffs = jnp.asarray(packed_coeff_table(num_segments))
-        in_specs.append(
-            pl.BlockSpec(coeffs.shape, lambda bh, i, j: (0, 0))
-        )
-        operands.append(coeffs)
 
     out = pl.pallas_call(
         kernel,
@@ -221,7 +209,7 @@ def flash_attention_fwd(
         out_specs=(
             [
                 pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-                pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
+                pl.BlockSpec((1, block_q, LANES), lambda bh, i, j: (bh, i, 0)),
             ]
             if return_lse
             else pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0))
@@ -229,7 +217,9 @@ def flash_attention_fwd(
         out_shape=(
             [
                 jax.ShapeDtypeStruct((batch * h, num_q * block_q, d), q.dtype),
-                jax.ShapeDtypeStruct((batch * h, num_q * block_q), jnp.float32),
+                jax.ShapeDtypeStruct(
+                    (batch * h, num_q * block_q, LANES), jnp.float32
+                ),
             ]
             if return_lse
             else jax.ShapeDtypeStruct((batch * h, num_q * block_q, d), q.dtype)
@@ -240,7 +230,7 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
-    )(*operands)
+    )(qh, kh, vh)
 
     if return_lse:
         out, lse = out

@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pwl_exp2 import LOG2_E
+from .kernel import LANES
 
 NEG_INF = -1e30
 
@@ -60,16 +61,16 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]      # [bq]
-    delta = delta_ref[0]  # [bq] = rowsum(dO * O)
+    lse = lse_ref[0][:, :1]      # [bq, 1] (stored lane-broadcast)
+    delta = delta_ref[0][:, :1]  # [bq, 1] = rowsum(dO * O)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = s + _mask_bias(i, j, block_q, block_k, causal, q_offset, seq_k, pad_k)
-    p = jnp.exp2(c * s - lse[:, None])  # recompute (never stored)
+    p = jnp.exp2(c * s - lse)  # recompute (never stored)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * sm_scale
+    ds = p * (dp - delta) * sm_scale
     acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
                                     preferred_element_type=jnp.float32)
 
@@ -95,18 +96,18 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]
-    delta = delta_ref[0]
+    lse = lse_ref[0][:, :1]
+    delta = delta_ref[0][:, :1]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     s = s + _mask_bias(i, j, block_q, block_k, causal, q_offset, seq_k, pad_k)
-    p = jnp.exp2(c * s - lse[:, None])  # [bq, bk]
+    p = jnp.exp2(c * s - lse)  # [bq, bk]
     dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * sm_scale  # [bq, bk]
+    ds = p * (dp - delta) * sm_scale  # [bq, bk]
     dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
                                        preferred_element_type=jnp.float32)
 
@@ -121,7 +122,7 @@ def flash_attention_bwd(
     k: jax.Array,   # [B, Sk, Hkv, d]
     v: jax.Array,   # [B, Sk, Hkv, d]
     out: jax.Array,  # [B, Sq, H, d] forward output
-    lse: jax.Array,  # [B*H, padded_Sq] base-2 LSE from the forward
+    lse: jax.Array,  # [B*H, padded_Sq, LANES] base-2 LSE from the forward
     do: jax.Array,  # [B, Sq, H, d]
     *,
     causal: bool = False,
@@ -157,8 +158,10 @@ def flash_attention_bwd(
         kh = jnp.pad(kh, ((0, 0), (0, pad_k), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, pad_k), (0, 0)))
 
-    # delta = rowsum(dO * O) (the FA2 preprocess; cheap, done in XLA).
+    # delta = rowsum(dO * O) (the FA2 preprocess; cheap, done in XLA),
+    # lane-broadcast like the LSE so its blocks are (8, 128)-tiled.
     delta = jnp.sum(doh.astype(jnp.float32) * oh.astype(jnp.float32), axis=-1)
+    delta = jnp.broadcast_to(delta[..., None], lse.shape)
 
     common = dict(block_q=block_q, block_k=block_k, causal=causal,
                   sm_scale=float(scale), q_offset=q_offset, seq_k=sk,
@@ -172,8 +175,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
-            pl.BlockSpec((1, block_q), lambda bh, i, j: (bh, i)),
+            pl.BlockSpec((1, block_q, LANES), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, LANES), lambda bh, i, j: (bh, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((batch * h, num_q * block_q, d), q.dtype),
@@ -190,8 +193,8 @@ def flash_attention_bwd(
             pl.BlockSpec((1, block_k, d), lambda bh, j, i, rep=rep: (bh // rep, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, j, i, rep=rep: (bh // rep, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_q), lambda bh, j, i: (bh, i)),
-            pl.BlockSpec((1, block_q), lambda bh, j, i: (bh, i)),
+            pl.BlockSpec((1, block_q, LANES), lambda bh, j, i: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, LANES), lambda bh, j, i: (bh, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0)),

@@ -14,24 +14,20 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.core.pwl_exp2 import packed_coeff_table, pwl_coeffs
+from repro.core.pwl_exp2 import pwl_coeffs
 
 DEFAULT_BLOCK_ROWS = 256
 LANES = 128
 
 
-def _kernel(x_ref, coeff_ref, o_ref, *, num_segments: int):
+def _kernel(x_ref, o_ref, *, num_segments: int):
     x = x_ref[...].astype(jnp.float32)
     x_i = jnp.ceil(x)
     x_f = x - x_i
     idx = jnp.clip(
         jnp.floor((x_f + 1.0) * num_segments).astype(jnp.int32), 0, num_segments - 1
     )
-    # One-hot segment select (see core.pwl_exp2.pwl_coeffs): vectorized and
-    # bit-identical to the unrolled where-chain it replaces.  The table
-    # arrives as a lane-aligned operand (kernels can't capture constants).
-    tables = (coeff_ref[0, :num_segments], coeff_ref[1, :num_segments])
-    slope, intercept = pwl_coeffs(idx, num_segments, tables)
+    slope, intercept = pwl_coeffs(idx, num_segments)
     frac = slope * x_f + intercept
     e = jnp.clip(x_i, -150.0, 127.0).astype(jnp.int32)
     out = jnp.where(x_i < -148, 0.0, jnp.ldexp(frac, e))
@@ -56,16 +52,12 @@ def pwl_exp2_pallas(
         flat = jnp.pad(flat, (0, padded - n))
     tiled = flat.reshape(num_blocks * block_rows, LANES)
 
-    coeffs = jnp.asarray(packed_coeff_table(num_segments, LANES))
     out = pl.pallas_call(
         functools.partial(_kernel, num_segments=num_segments),
         grid=(num_blocks,),
-        in_specs=[
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec(coeffs.shape, lambda i: (0, 0)),
-        ],
+        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(tiled.shape, orig_dtype),
         interpret=interpret,
-    )(tiled, coeffs)
+    )(tiled)
     return out.reshape(-1)[:n].reshape(orig_shape)

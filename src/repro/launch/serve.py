@@ -1,6 +1,9 @@
-"""Serving launcher: continuous batching against a (smoke-config) model.
+"""Serving launcher: continuous batching against a registry model.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --requests 8
+  PYTHONPATH=src python -m repro.launch.serve --arch yi-9b --smoke --requests 8
+
+Without ``--smoke`` the arch's full published config is served (random
+init, seeded); ``--smoke`` selects its reduced config (CPU-runnable).
 
 Requests get mixed prompt lengths (the engine buckets them for prefill),
 arrive all at once, and drain through a fixed slot pool — so this drives
@@ -9,7 +12,8 @@ prefill bucketing, slot eviction and back-fill even in a smoke run.
   --temperature/--top-k/--top-p  sampling policy (default greedy)
   --chunk N                      chunked flash prefill (N tokens per call)
   --mesh DxM                     shard params + decode cache over a debug
-                                 mesh (data x model), e.g. --mesh 2x4
+                                 mesh (data x model), e.g. --mesh 2x4; both
+                                 are created sharded
   --quant int8                   int8 projections + int8 KV cache
                                  (repro.quant; greedy outputs stay
                                  token-identical to sequential decode,
@@ -18,7 +22,8 @@ prefill bucketing, slot eviction and back-fill even in a smoke run.
                                  drafts with the target itself (lossless
                                  sanity mode, acceptance = 1.0); an arch id
                                  drafts with that smoke config (random
-                                 init in this launcher)
+                                 init in this launcher; the vocabularies
+                                 must match, so pair it with --smoke)
   --spec-k N                     lookahead: draft tokens verified per round
   --spec-quant int8              int8 policy on the *draft* only (the
                                  near-free draft / exact target split)
@@ -41,8 +46,9 @@ import time
 import jax
 import numpy as np
 
-from repro.configs.registry import get_smoke_config
-from repro.models import init_params
+from repro.configs.registry import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
+from repro.models import init_params, param_shapes
 from repro.obs import Tracer, set_tracer, watch_jit_compiles
 from repro.quant.config import QUANT_FLAGS
 from repro.serve import Request, SamplingConfig, ServeEngine, sequential_greedy_decode
@@ -50,7 +56,8 @@ from repro.serve import Request, SamplingConfig, ServeEngine, sequential_greedy_
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="yi-9b")
+    ap.add_argument("--arch", required=True)
+    ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
     ap.add_argument("--quant", default="none", choices=QUANT_FLAGS,
                     help="int8 policy: projections + int8 KV cache "
                          "(int8-kv-only / int8-no-kv select one half)")
@@ -80,19 +87,24 @@ def main() -> None:
                     help="write a Perfetto-loadable Chrome trace here")
     args = ap.parse_args()
 
-    cfg = get_smoke_config(args.arch, args.quant)
+    cfg = (get_smoke_config if args.smoke else get_config)(args.arch, args.quant)
     if cfg.family == "encoder":
         raise SystemExit("encoder-only arch: no decode phase (DESIGN.md §5)")
+    enable_compile_cache()
 
     mesh = None
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    key = jax.random.PRNGKey(0)
     if args.mesh:
         from repro.dist.sharding import param_shardings
         from repro.launch.mesh import make_debug_mesh
 
         data, model = (int(x) for x in args.mesh.split("x"))
         mesh = make_debug_mesh(data, model)
-        params = jax.device_put(params, param_shardings(params, cfg, mesh))
+        # Created sharded: no device ever holds the whole model.
+        sh = param_shardings(param_shapes(cfg), cfg, mesh)
+        params = jax.jit(init_params, static_argnums=0, out_shardings=sh)(cfg, key)
+    else:
+        params = init_params(cfg, key)
 
     sampling = SamplingConfig(
         temperature=args.temperature, top_k=args.top_k, top_p=args.top_p,

@@ -26,6 +26,7 @@ import dataclasses
 
 from repro.configs.base import ShapeConfig
 from repro.configs.registry import get_config, get_smoke_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import Tracer, set_tracer
 from repro.quant.config import QUANT_FLAGS
 from repro.train.trainer import Trainer, TrainerConfig
@@ -57,6 +58,7 @@ def main() -> None:
     cfg = (get_smoke_config if args.smoke else get_config)(args.arch, args.quant)
     if cfg.family == "encoder" and not cfg.embedding_inputs:
         raise SystemExit("encoder archs train on frame embeddings")
+    enable_compile_cache()
     shape = ShapeConfig("cli", args.seq, args.batch, "train")
     tcfg = TrainerConfig(
         total_steps=args.steps,

@@ -1,11 +1,16 @@
 """GQA attention layer: params, forward (train/prefill), decode with KV cache.
 
-The paper's technique enters here: ``cfg.attention_impl`` selects
+The paper's technique enters here.  Full-sequence attention (training and
+prefill) runs
 
+  * ``pallas``   — the fused Pallas TPU kernel (``repro.kernels``), compiled;
+    the default on TPU;
   * ``systolic`` — the Algorithm-1-faithful tiled jnp implementation
-    (``repro.core.attention``), lowers on all backends; the dry-run path;
-  * ``pallas``   — the fused Pallas TPU kernel (``repro.kernels``);
-  * ``naive``    — materialized softmax (oracle / tiny decode steps).
+    (``repro.core.attention``), lowers on all backends; the default
+    elsewhere (tests, the CPU dry-run) and the f32 reference;
+  * ``naive``    — materialized softmax (oracle).
+
+``cfg.attention_impl`` forces one; None picks from the platform.
 
 Per the paper §8.3, decode (seq_q == 1, memory-bound) never uses the FSA
 path: a 1-token query would waste a 128x128 tile.  ``decode_attention``
@@ -21,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.core.attention import naive_attention, systolic_attention
+from repro.dist.collectives import map_heads
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.quant import dequantize_kv, get_quant, quantize_kv
 from .layers import apply_mrope, apply_rope, dense_init, rms_norm
@@ -100,13 +106,19 @@ def _impl_attention(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
     numerics for the same (q, k, v) — the token-equivalence contract of
     the serving engine depends on this.
     """
-    if cfg.attention_impl == "naive":
+    impl = cfg.attention_impl
+    if impl is None:
+        impl = "pallas" if jax.default_backend() == "tpu" else "systolic"
+    if impl == "naive":
         return naive_attention(q, k, v, causal=cfg.causal, q_offset=q_offset)
-    if cfg.attention_impl == "pallas":
-        return flash_attention(
-            q, k, v, cfg.causal, None, q_offset,
-            cfg.attn_block_q, cfg.attn_block_k, cfg.exp2_impl, 8, "pallas",
-        )
+    if impl == "pallas":
+        def kernel(q, k, v):
+            return flash_attention(
+                q, k, v, cfg.causal, None, q_offset,
+                cfg.attn_block_q, cfg.attn_block_k, cfg.exp2_impl, 8, "pallas",
+            )
+
+        return map_heads(kernel, q, k, v)
     # systolic (paper-faithful jnp; dry-run / CPU path)
     return systolic_attention(
         q, k, v,
@@ -203,6 +215,16 @@ def init_kv_cache(cfg: ModelConfig, batch: int, max_len: int, dtype):
     )
 
 
+def _pv(p: jax.Array, v: jax.Array) -> jax.Array:
+    """P·V of the cached-decode einsums with P kept in fp32.  TPU's default
+    f32 matmul rounds P to bf16; the prefill kernel does not, and decode
+    should agree with prefill as closely as the activation dtype allows."""
+    return jnp.einsum(
+        "bhrqk,bkhd->bqhrd", p, v.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
 def verify_attention(
     x: jax.Array,  # [B, S, d_model] — S teacher-forced tokens per slot
     params: dict,
@@ -266,7 +288,7 @@ def verify_attention(
     )
     s = jnp.where(valid, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhrqk,bkhd->bqhrd", p, v.astype(jnp.float32)).astype(x.dtype)
+    o = _pv(p, v).astype(x.dtype)
     o = o.reshape(b, s_new, cfg.num_heads * hd)
     return get_quant(cfg).dot(o, params["wo"], "attention"), new_cache
 
@@ -326,6 +348,6 @@ def decode_attention(
     )
     s = jnp.where(valid, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhrqk,bkhd->bqhrd", p, v.astype(jnp.float32)).astype(x.dtype)
+    o = _pv(p, v).astype(x.dtype)
     o = o.reshape(b, 1, cfg.num_heads * hd)
     return get_quant(cfg).dot(o, params["wo"], "attention"), new_cache

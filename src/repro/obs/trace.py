@@ -9,8 +9,8 @@ microseconds on a per-tracer monotonic epoch (``time.perf_counter``).
 Spans come in two forms:
 
   * ``with tracer.span("prefill", args={"rid": 3}):`` — measures the
-    enclosed block.  When jax exposes ``jax.profiler.TraceAnnotation`` the
-    span name is passed through to it too, so the same annotation shows up
+    enclosed block.  The span name is passed through to
+    ``jax.profiler.TraceAnnotation`` too, so the same annotation shows up
     in a jax-native profile when one is being captured.
   * ``tracer.complete(name, start_s, dur_s)`` — retroactive span from
     host-side timestamps already on hand (e.g. a request's queue-wait
@@ -32,18 +32,9 @@ import threading
 import time
 from typing import Optional
 
+import jax
+
 __all__ = ["Tracer", "NullTracer", "get_tracer", "set_tracer"]
-
-
-def _jax_trace_annotation():
-    """``jax.profiler.TraceAnnotation`` when this jax has it, else None.
-    Resolved lazily so importing repro.obs never forces jax init."""
-    try:
-        import jax
-
-        return getattr(jax.profiler, "TraceAnnotation", None)
-    except Exception:  # pragma: no cover - jax always importable here
-        return None
 
 
 class Tracer:
@@ -56,7 +47,6 @@ class Tracer:
         self.events: list[dict] = []
         self._lock = threading.Lock()
         self._epoch = time.perf_counter()
-        self._annotation = _jax_trace_annotation()
         # Metadata record naming the process lane in the Perfetto UI.
         self.events.append(
             {
@@ -89,14 +79,10 @@ class Tracer:
         """Measure the enclosed block as a complete ("X") event."""
         tid = threading.get_ident() % 2**31 if tid is None else tid
         t0 = self.now_s()
-        ann = self._annotation(name) if self._annotation is not None else None
-        if ann is not None:
-            ann.__enter__()
         try:
-            yield self
+            with jax.profiler.TraceAnnotation(name):
+                yield self
         finally:
-            if ann is not None:
-                ann.__exit__(None, None, None)
             self.complete(name, t0, self.now_s() - t0, cat=cat, tid=tid,
                           args=args)
 

@@ -40,7 +40,6 @@ decode spans on its slot's lane.
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import time
 from collections import deque
@@ -51,6 +50,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.dist.collectives import mesh_context
 from repro.models import decode_step, init_cache, insert_cache, prefill_step
 from repro.obs import MFUMeter, Registry, get_tracer
 from .serve_step import SamplingConfig, make_decode_step, sample_logits
@@ -253,9 +253,21 @@ class ServeEngine:
             # compile_counts() meaningless.
             return insert_cache(cache, prefix, slot)
 
+        # Under a mesh the cache keeps the layout it is created with through
+        # every insert and decode step, so each compiles once.
+        self._cache_sh = None
+        if mesh is not None:
+            from repro.dist.sharding import cache_shardings
+
+            self._cache_sh = cache_shardings(
+                jax.eval_shape(self._new_cache), cfg, mesh
+            )
         self._prefill_jit = jax.jit(_prefill)
-        self._insert_jit = jax.jit(_insert)
-        self._decode_jit = jax.jit(make_decode_step(cfg, sampling=scfg))
+        self._insert_jit = jax.jit(_insert, out_shardings=self._cache_sh)
+        self._decode_jit = jax.jit(
+            make_decode_step(cfg, sampling=scfg),
+            out_shardings=(None, None, self._cache_sh),
+        )
 
     # -- introspection ------------------------------------------------------
 
@@ -307,21 +319,17 @@ class ServeEngine:
 
     # -- engine phases ------------------------------------------------------
 
-    def _mesh_ctx(self):
-        return jax.set_mesh(self.mesh) if self.mesh is not None else contextlib.nullcontext()
+    def _new_cache(self):
+        return init_cache(self.cfg, self.batch, self.max_len)
 
     def _ensure_cache(self) -> None:
         if self.cache is not None:
             return
-        with self._mesh_ctx():
-            cache = init_cache(self.cfg, self.batch, self.max_len)
-        if self.mesh is not None:
-            from repro.dist.sharding import cache_shardings
-
-            cache = jax.device_put(
-                cache, cache_shardings(cache, self.cfg, self.mesh)
-            )
-        self.cache = cache
+        if self.mesh is None:
+            self.cache = self._new_cache()
+        else:
+            # Created sharded: each device allocates only its own shards.
+            self.cache = jax.jit(self._new_cache, out_shardings=self._cache_sh)()
 
     def _prefill_into_slot(self, req: Request, slot: int) -> int:
         """Prefill ``req`` (one jit call) and insert it into ``slot``."""
@@ -332,7 +340,7 @@ class ServeEngine:
         key = jax.random.fold_in(self._base_key, self._prefill_idx)
         self._prefill_idx += 1
         req.t_prefill = t0 = time.perf_counter()
-        with self._mesh_ctx(), self.tracer.span(
+        with mesh_context(self.mesh), self.tracer.span(
             "prefill", cat="serve", tid=slot,
             args={"rid": req.rid, "len": plen, "bucket": bucket},
         ):
@@ -433,7 +441,7 @@ class ServeEngine:
             jnp.asarray(self._positions),
         )
         t0 = time.perf_counter()
-        with self._mesh_ctx(), self.tracer.span(
+        with mesh_context(self.mesh), self.tracer.span(
             "generate", cat="serve", tid=0,
             args={"live": len(live), "step": self._step_idx},
         ):
@@ -483,7 +491,7 @@ class ServeEngine:
             [self._next_tok[:, None], drafts], axis=1
         ).astype(np.int32)
         t1 = time.perf_counter()
-        with self._mesh_ctx(), self.tracer.span(
+        with mesh_context(self.mesh), self.tracer.span(
             "verify", cat="serve", tid=0, args={"live": len(live), "k": k}
         ):
             greedy, accepted, self.cache = self._verify_jit(
@@ -561,16 +569,17 @@ def sequential_greedy_decode(
     exactly this."""
     plen = len(prompt)
     max_len = max_len or plen + max_new_tokens
+    step = jax.jit(decode_step, static_argnums=1)
     cache = init_cache(cfg, 1, max_len)
     logits = None
     for i in range(plen):
         t = jnp.asarray([[int(prompt[i])]], jnp.int32)
-        logits, cache = decode_step(params, cfg, t, cache, jnp.asarray(i, jnp.int32))
+        logits, cache = step(params, cfg, t, cache, jnp.asarray(i, jnp.int32))
     out = [int(jnp.argmax(logits[0, -1]))]
     pos = plen
     while len(out) < max_new_tokens and out[-1] != eos_id and pos < max_len:
         t = jnp.asarray([[out[-1]]], jnp.int32)
-        logits, cache = decode_step(params, cfg, t, cache, jnp.asarray(pos, jnp.int32))
+        logits, cache = step(params, cfg, t, cache, jnp.asarray(pos, jnp.int32))
         out.append(int(jnp.argmax(logits[0, -1])))
         pos += 1
     return out

@@ -16,13 +16,10 @@ is kept.  Spans go to the ambient tracer (``--trace-out`` installs one).
 
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import time
 from typing import Callable, Optional
-
-_NULL_CTX = contextlib.nullcontext()
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +28,7 @@ import numpy as np
 from repro.checkpoint import CheckpointManager
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.data import DataConfig, make_source
+from repro.dist.collectives import mesh_context
 from repro.dist.fault import PreemptionHandler, StepWatchdog
 from repro.models import init_params, lm_loss
 from repro.obs import MFUMeter, Registry, get_tracer
@@ -113,53 +111,56 @@ class Trainer:
         )
         self.step_fn = jax.jit(step)
 
-    def _shard_state(self, state: dict) -> dict:
-        """Place params (and the compression residual) per the TP rules when
-        a mesh is given; the jit then reads the layout off the arrays."""
-        if self.mesh is None:
-            return state
-        from repro.dist.sharding import param_shardings
-
-        sh = param_shardings(state["params"], self.cfg, self.mesh)
-        out = dict(state)
-        out["params"] = jax.device_put(state["params"], sh)
-        if "residual" in state:
-            out["residual"] = jax.device_put(state["residual"], sh)
-        return out
-
     # -- state ------------------------------------------------------------
 
-    def init_state(self) -> dict:
+    def _make_state(self) -> dict:
         params = init_params(self.cfg, jax.random.PRNGKey(self.tcfg.seed))
-        state = {
-            "params": params,
-            "opt": self.optimizer.init(params),
-            "step": 0,
-        }
+        state = {"params": params, "opt": self.optimizer.init(params)}
         if self.tcfg.compress_grads:
             state["residual"] = init_residual(params)
+        return state
+
+    def _state_shardings(self, shapes: dict) -> dict:
+        """TP layout for params (and the compression residual), ZeRO-1 for
+        the optimizer state."""
+        from repro.dist.sharding import param_shardings, zero1_shardings
+
+        sh = param_shardings(shapes["params"], self.cfg, self.mesh)
+        out = {"params": sh, "opt": zero1_shardings(shapes["opt"], self.cfg, self.mesh)}
+        if "residual" in shapes:
+            out["residual"] = sh
+        return out
+
+    def init_state(self) -> dict:
+        """Fresh state.  With a mesh it is created sharded: each device
+        materializes only its own shards, never the whole state first."""
+        if self.mesh is None:
+            state = self._make_state()
+        else:
+            shapes = jax.eval_shape(self._make_state)
+            state = jax.jit(
+                self._make_state, out_shardings=self._state_shardings(shapes)
+            )()
+        state["step"] = 0
         return state
 
     def restore_or_init(self) -> dict:
         latest = self.ckpt.latest_step()
         if latest is None:
             return self.init_state()
-        template = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            {k: v for k, v in self.init_state().items() if k != "step"},
-        )
-        restored = self.ckpt.restore(latest, template)
+        template = jax.eval_shape(self._make_state)
+        shardings = self._state_shardings(template) if self.mesh else None
+        restored = self.ckpt.restore(latest, template, shardings)
         restored["step"] = latest
         return restored
 
     # -- loop --------------------------------------------------------------
 
     def run(self, state: Optional[dict] = None) -> dict:
-        state = self._shard_state(state or self.restore_or_init())
+        state = state or self.restore_or_init()
         ckpt_keys = ("params", "opt") + (
             ("residual",) if self.tcfg.compress_grads else ()
         )
-        mesh_ctx = self.mesh or _NULL_CTX
         losses = []
         tokens_per_batch = self.shape.global_batch * self.shape.seq_len
         jsonl = (
@@ -173,7 +174,7 @@ class Trainer:
             step = state["step"]
             batch = {k: jnp.asarray(v) for k, v in self.data.batch(step).items()}
             self.watchdog.start_step()
-            with mesh_ctx, self.tracer.span(
+            with mesh_context(self.mesh), self.tracer.span(
                 "train_step", cat="train", tid=0, args={"step": step}
             ):
                 if self.tcfg.compress_grads:

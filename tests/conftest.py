@@ -5,20 +5,14 @@
    multi-device mesh.  (Individual test modules also set this defensively
    for standalone runs, but the backend is process-global — it must be in
    the environment before the first device query anywhere in the session.)
-2. If `hypothesis` is not installed, register the deterministic stub from
-   ``_hypothesis_stub.py`` under its name so the property tests still run
-   (with plain random sampling instead of real shrinking search).
-3. Provide the ``jit_recompiles`` fixture: an XLA-compilation counter the
+2. Provide the ``jit_recompiles`` fixture: an XLA-compilation counter the
    serving tests use to pin "compiles once per prefill bucket, never per
    prompt length".  Since PR 10 it is a thin wrapper over the library
    counter ``repro.obs.JitCompileWatcher`` (same log-record mechanism,
    now also wirable into a metrics registry).
 """
 
-import importlib.util
 import os
-import sys
-from pathlib import Path
 
 import pytest
 
@@ -26,16 +20,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     )
-
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    _stub_path = Path(__file__).with_name("_hypothesis_stub.py")
-    _spec = importlib.util.spec_from_file_location("hypothesis", _stub_path)
-    _stub = importlib.util.module_from_spec(_spec)
-    _spec.loader.exec_module(_stub)
-    sys.modules["hypothesis"] = _stub
-    sys.modules["hypothesis.strategies"] = _stub.strategies
 
 
 @pytest.fixture

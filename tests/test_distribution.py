@@ -81,6 +81,44 @@ def test_sharded_forward_matches_unsharded():
     )
 
 
+@pytest.mark.parametrize("heads,kv_heads", [(8, 4), (8, 2)])
+def test_map_heads_matches_unsharded(heads, kv_heads):
+    """Attention run per device shard under a 2x4 mesh == whole-array
+    attention.  (8, 2): "model" does not divide the KV heads, which then
+    stay whole on every device."""
+    from repro.core.attention import naive_attention
+    from repro.dist.collectives import map_heads
+
+    def attn(q, k, v):
+        return naive_attention(q, k, v, causal=True)
+
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    q = jax.random.normal(ks[0], (4, 32, heads, 16))
+    k = jax.random.normal(ks[1], (4, 32, kv_heads, 16))
+    v = jax.random.normal(ks[2], (4, 32, kv_heads, 16))
+    with jax.set_mesh(make_debug_mesh(2, 4)):
+        out = jax.jit(lambda q, k, v: map_heads(attn, q, k, v))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(attn(q, k, v)), atol=1e-5)
+
+
+def test_params_created_sharded_equal_unsharded_init():
+    """``init_params`` jitted with ``out_shardings`` (how the launchers create
+    a model too large for one device) gives the same values as on one
+    device, laid out per the TP rules."""
+    from repro.dist.sharding import param_shardings
+
+    cfg = get_smoke_config("yi-9b")
+    mesh = make_debug_mesh(2, 4)
+    sh = param_shardings(param_shapes(cfg), cfg, mesh)
+    sharded = jax.jit(init_params, static_argnums=0, out_shardings=sh)(
+        cfg, jax.random.PRNGKey(0))
+    ref = jax.jit(init_params, static_argnums=0)(cfg, jax.random.PRNGKey(0))
+    for a, b, s in zip(jax.tree.leaves(sharded), jax.tree.leaves(ref),
+                       jax.tree.leaves(sh)):
+        assert a.sharding == s
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
 def test_moe_shard_map_matches_local_no_drop():
     cfg = get_smoke_config("qwen3-moe-235b-a22b")
     cfg = dataclasses.replace(

@@ -228,3 +228,13 @@ def test_pallas_custom_vjp_end_to_end():
     gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b in zip(g, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def test_pallas_path_without_interpret_raises_off_tpu():
+    """Off TPU the compiled kernel path must fail loudly, never fall back
+    to interpret mode or to the scan."""
+    if jax.default_backend() == "tpu":
+        pytest.skip("compiled kernels run on TPU")
+    q = _rand((1, 128, 2, 32), 0)
+    with pytest.raises(ValueError, match="interpret"):
+        flash_attention(q, q, q, True, None, 0, 64, 64, "exact", 8, "pallas")

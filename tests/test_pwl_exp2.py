@@ -57,7 +57,11 @@ def test_monotone_in_segments(k, x):
     """More segments never increases the error (chord construction)."""
     e_k = abs(float(pwl_exp2(jnp.float32(x), num_segments=k)) - float(np.exp2(np.float64(x))))
     e_2k = abs(float(pwl_exp2(jnp.float32(x), num_segments=2 * k)) - float(np.exp2(np.float64(x))))
-    assert e_2k <= e_k + 1e-9
+    # Slack of one fp32 ulp of exp2(x): both tables are fp32, so where the
+    # two segmentations share a knot (e.g. x = -0.5 for every k) the two
+    # errors are only the fp32 rounding of the same chord value.
+    ulp = float(np.spacing(np.float32(np.exp2(np.float64(x)))))
+    assert e_2k <= e_k + ulp
 
 
 def test_flush_to_zero():

@@ -167,11 +167,16 @@ def test_eos_truncates_and_matches_reference(params):
     assert r.output == ref
 
 
-def test_generate_compiles_once_per_bucket(params, jit_recompiles):
+@pytest.mark.parametrize("mesh_shape", [None, (2, 4)])
+def test_generate_compiles_once_per_bucket(params, jit_recompiles, mesh_shape):
     """Prefill compiles once per bucket, generate exactly once; a second
-    wave of new prompt lengths (same buckets) compiles nothing."""
+    wave of new prompt lengths (same buckets) compiles nothing.  Under a
+    mesh too: the cache keeps its layout through insert and decode."""
+    from repro.launch.mesh import make_debug_mesh
+
+    mesh = make_debug_mesh(*mesh_shape) if mesh_shape else None
     eng = ServeEngine(TINY, params, batch_size=2, max_len=MAX_LEN,
-                      prefill_buckets=(8, 16))
+                      prefill_buckets=(8, 16), mesh=mesh)
     wave1 = [(5, 3), (8, 3), (12, 3), (16, 3)]  # both buckets, both edges
     for i, p in enumerate(_prompts(wave1, seed=1)):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=3))

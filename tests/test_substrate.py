@@ -269,3 +269,61 @@ def test_error_feedback_preserves_signal():
         q, s, residual = compress_with_feedback(g, residual)
         total = total + dequantize_int8(q["w"], s["w"])
     np.testing.assert_allclose(np.asarray(total / 100), np.asarray(g["w"]), rtol=0.02)
+
+
+@pytest.mark.parametrize("env_set", [False, True])
+def test_compile_cache_dir(env_set, tmp_path, monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache goes to
+    one fixed directory at the root of the checkout."""
+    from repro.launch import compile_cache
+
+    assert (compile_cache.CHECKOUT_CACHE_DIR.parent / "chip_smoke.py").is_file()
+    was = jax.config.jax_compilation_cache_dir
+    was_regex = jax.config.jax_hlo_source_file_canonicalization_regex
+    if env_set:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    try:
+        got = compile_cache.enable_compile_cache()
+        if env_set:
+            assert got == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == was  # set nothing
+        else:
+            assert got == str(compile_cache.CHECKOUT_CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == got
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", was_regex)
+
+
+def test_compile_cache_key_has_no_checkout_path():
+    """The Pallas kernel reaches the compiler as serialized Mosaic code with
+    its source file in it.  After ``enable_compile_cache`` that file name is
+    relative to ``src/``, so two checkouts at different paths share cache
+    entries.  (Lowered for TPU through ``jax.export``; needs no chip.)"""
+    import base64
+    import re
+
+    from repro.kernels.flash_attention.kernel import flash_attention_fwd
+    from repro.launch import compile_cache
+
+    def mosaic_body():
+        q = jax.ShapeDtypeStruct((1, 128, 1, 128), jnp.bfloat16)
+        f = jax.jit(lambda q: flash_attention_fwd(q, q, q))
+        text = jax.export.export(f, platforms=["tpu"])(q).mlir_module()
+        body = re.search(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)', text).group(1)
+        return base64.b64decode(body)
+
+    src = str(compile_cache.SRC_DIR).encode()
+    was = jax.config.jax_hlo_source_file_canonicalization_regex
+    was_dir = jax.config.jax_compilation_cache_dir
+    try:
+        assert src in mosaic_body()
+        compile_cache.enable_compile_cache()
+        body = mosaic_body()
+        assert src not in body
+        assert b"repro/kernels/flash_attention/kernel.py" in body
+    finally:
+        jax.config.update("jax_hlo_source_file_canonicalization_regex", was)
+        jax.config.update("jax_compilation_cache_dir", was_dir)

@@ -1,0 +1,120 @@
+"""The Pallas kernels compile for a TPU v5e at olmo-1b attention width.
+
+Interpret mode (tests/test_kernels.py) checks numerics but not what the
+chip's compiler accepts: block tiling, VMEM use, partitioning.  These tests
+compile for a described v5e:2x2 topology, which needs the TPU compiler but
+no chip, at q/k/v ``[1, 2048, 16, 128]`` bf16, causal.  Nothing runs.
+
+The topology is described inside a fixture, never at import: only one
+process may load the TPU library, and every test worker imports this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from repro.dist.collectives import map_heads, mesh_context
+from repro.kernels.flash_attention.kernel import LANES, flash_attention_fwd
+from repro.kernels.flash_attention.kernel_bwd import flash_attention_bwd
+from repro.kernels.flash_attention.ops import flash_attention
+from repro.kernels.pwl_exp2.kernel import pwl_exp2_pallas
+
+B, S, H, D = 1, 2048, 16, 128
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else it logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or its library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args, mesh=None):
+    with mesh_context(mesh):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text  # the Mosaic kernel is in the program
+    return text
+
+
+def _qkv(sharding, sq=S, sk=S):
+    q = jax.ShapeDtypeStruct((B, sq, H, D), jnp.bfloat16, sharding=sharding)
+    kv = jax.ShapeDtypeStruct((B, sk, H, D), jnp.bfloat16, sharding=sharding)
+    return q, kv, kv
+
+
+# Each case: (name, build(sharding) -> (fn, args)).
+CASES = {
+    "fwd": lambda sh: (
+        lambda q, k, v: flash_attention_fwd(q, k, v, causal=True), _qkv(sh)),
+    "fwd_lse": lambda sh: (
+        lambda q, k, v: flash_attention_fwd(q, k, v, causal=True, return_lse=True),
+        _qkv(sh)),
+    "fwd_pwl": lambda sh: (
+        lambda q, k, v: flash_attention_fwd(q, k, v, causal=True, exp2_impl="pwl"),
+        _qkv(sh)),
+    # Chunked prefill: the second 512-token chunk against 1024 cached keys.
+    "fwd_q_offset": lambda sh: (
+        lambda q, k, v: flash_attention_fwd(q, k, v, causal=True, q_offset=512),
+        _qkv(sh, sq=512, sk=1024)),
+    # dq grid and dkv grid, with the lane-broadcast LSE the forward stores.
+    "bwd_dq_dkv": lambda sh: (
+        lambda q, k, v, o, lse, do: flash_attention_bwd(q, k, v, o, lse, do, causal=True),
+        (*_qkv(sh), _qkv(sh)[0],
+         jax.ShapeDtypeStruct((B * H, S, LANES), jnp.float32, sharding=sh),
+         _qkv(sh)[0])),
+    # The training path: custom_vjp forward with LSE, then both grids.
+    "train_grad": lambda sh: (
+        jax.grad(lambda q, k, v: flash_attention(
+            q, k, v, True, None, 0, 128, 128, "exact", 8, "pallas"
+        ).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
+        _qkv(sh)),
+    "pwl_exp2": lambda sh: (
+        pwl_exp2_pallas,
+        (jax.ShapeDtypeStruct((1024, 1024), jnp.float32, sharding=sh),)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
+    fn, args = CASES[case](one_chip)
+    _compile(fn, *args)
+
+
+def test_kernel_under_mesh_compiles_for_four_chips(topo, no_compile_cache):
+    """Under a 1x4 (data x model) mesh the kernel runs per head shard inside
+    shard_map (``map_heads``); GSPMD alone cannot partition it."""
+    mesh = Mesh(np.array(topo.devices).reshape(1, 4), ("data", "model"))
+    sh = NamedSharding(mesh, P(None, None, "model", None))
+    text = _compile(
+        lambda q, k, v: map_heads(
+            lambda q, k, v: flash_attention_fwd(q, k, v, causal=True), q, k, v),
+        *_qkv(sh), mesh=mesh,
+    )
+    assert "all-gather" not in text  # each chip attends its own heads
