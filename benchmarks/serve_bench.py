@@ -79,6 +79,7 @@ def run(csv_rows: list) -> dict:
     ttft = engine.registry.get("serve_ttft_seconds")
     tpot = engine.registry.get("serve_tpot_seconds")
     butil = engine.registry.get("serve_batch_utilization")
+    mfu = engine.registry.get("mfu")
     result = {
         "benchmark": "serve_decode",
         "decode_tokens_per_s": round(tok_s, 1),
@@ -93,7 +94,8 @@ def run(csv_rows: list) -> dict:
             "tpot_p99_ms": round(tpot.percentile(99) * 1e3, 3),
             "batch_utilization_mean": round(butil.sum / max(butil.count, 1), 4),
         },
-        "mfu_decode": engine.registry.get("mfu").labels(phase="decode").value,
+        # None on a device with no known peak (repro.obs.peaks).
+        "mfu_decode": mfu.labels(phase="decode").value if mfu else None,
         "model": {
             "family": CFG.family,
             "num_layers": CFG.num_layers,

@@ -230,6 +230,10 @@ def flash_attention_fwd(
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,
+        # The tag lands in the custom call's frontend attributes
+        # (``kernel_metadata``), which device traces print with each call.
+        name="flash_fwd",
+        metadata={"kernel": "flash_fwd"},
     )(qh, kh, vh)
 
     if return_lse:

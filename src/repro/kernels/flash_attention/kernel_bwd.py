@@ -182,6 +182,8 @@ def flash_attention_bwd(
         out_shape=jax.ShapeDtypeStruct((batch * h, num_q * block_q, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
+        metadata={"kernel": "flash_dq"},
     )(qh, kh, vh, doh, lse, delta)
 
     # dk/dv at q-head granularity; sum the rep partials afterwards.
@@ -209,6 +211,8 @@ def flash_attention_bwd(
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
+        metadata={"kernel": "flash_dkv"},
     )(qh, kh, vh, doh, lse, delta)
 
     def unhead(x, heads, s):

@@ -59,5 +59,7 @@ def pwl_exp2_pallas(
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(tiled.shape, orig_dtype),
         interpret=interpret,
+        name="pwl_exp2",
+        metadata={"kernel": "pwl_exp2"},
     )(tiled)
     return out.reshape(-1)[:n].reshape(orig_shape)

@@ -16,8 +16,11 @@ from __future__ import annotations
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12  # bf16 per chip
-HBM_BW = 819e9  # bytes/s per chip
+from repro.obs.peaks import PEAKS
+
+_V5E = PEAKS["TPU v5 lite"]
+PEAK_FLOPS = _V5E["bf16_flops_per_s"]  # per chip
+HBM_BW = _V5E["hbm_bytes_per_s"]  # per chip
 LINK_BW = 50e9  # bytes/s per ICI link
 
 _DTYPE_BYTES = {

@@ -89,7 +89,8 @@ def main() -> None:
           f"loss {state['losses'][0]:.4f} -> {state['losses'][-1]:.4f}")
     mfu = trainer.registry.get("mfu")
     if mfu is not None:
-        print(f"mfu (train, vs FSA array peak): {mfu.labels(phase='train').value:.3e}")
+        print(f"mfu (train, vs the devices' bf16 peak): "
+              f"{mfu.labels(phase='train').value:.4f}")
     if args.metrics_out:
         trainer.registry.dump(args.metrics_out)
         print(f"metrics -> {args.metrics_out} (+ {tcfg.metrics_jsonl})")

@@ -10,8 +10,8 @@ Zero-dependency observability substrate (ISSUE 10).  Three pieces:
     (``{"ph": "X", "ts": ...}``) with ``jax.profiler.TraceAnnotation``
     pass-through; ``NullTracer`` is the free disabled twin.
   * :mod:`repro.obs.mfu` — model-FLOPs-utilization accounting against the
-    paper's FSA array peak, reusing ``core.systolic_model`` closed forms
-    for the Fig. 11 paper-ideal reference.
+    device's bf16 peak (:mod:`repro.obs.peaks`, keyed by ``device_kind``),
+    with the paper's FSA array kept as the Fig. 11 paper-ideal reference.
 
 The serve engine, trainer, and fault-tolerance layer all report through
 this package; ``launch/serve.py --metrics-out m.prom --trace-out t.json``
@@ -35,11 +35,13 @@ from .mfu import (
     ArrayConfig,
     MFUMeter,
     decode_flops,
+    matmul_param_count,
     paper_ideal_flops_per_s,
     prefill_flops,
     train_step_flops,
     verify_flops,
 )
+from .peaks import PEAKS, device_peak
 from .trace import NullTracer, Tracer, get_tracer, set_tracer
 
 __all__ = [
@@ -60,6 +62,9 @@ __all__ = [
     "ArrayConfig",
     "PAPER_ARRAY",
     "MFUMeter",
+    "PEAKS",
+    "device_peak",
+    "matmul_param_count",
     "train_step_flops",
     "prefill_flops",
     "decode_flops",

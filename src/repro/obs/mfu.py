@@ -1,25 +1,19 @@
-"""Model-FLOPs-utilization (MFU) accounting against the paper's FSA array.
+"""Model-FLOPs-utilization (MFU) accounting against the device's peak.
 
-The paper's headline metric (Fig. 11) is attention FLOPs/s utilization:
-achieved FLOPs divided by the array's peak.  This module makes the repo
-report that metric about its *own* execution:
+  * closed-form model FLOPs per phase: 2 FLOPs per matmul parameter per
+    token forward (the output head counts, tied or not; the embedding
+    lookup does not), 3x the forward for training, plus the causal
+    attention term ``4 * head_dim * heads`` per layer for each query-key
+    pair on or below the diagonal; recomputation under remat is not
+    counted;
+  * ``MFUMeter`` divides achieved FLOPs/s by the bf16 peak of the device
+    (``repro.obs.peaks``, keyed by ``device_kind``) and keeps per-phase
+    gauges (``model_flops_per_s``, ``mfu``) and a cumulative FLOPs
+    counter.  On a device with no known peak it keeps no ``mfu`` gauge.
 
-  * closed-form model FLOPs per phase — PaLM-appendix accounting
-    (2 FLOPs per active parameter per token forward, 3x for the backward
-    pass) plus the causal attention term ``4 * ctx * head_dim * heads``
-    per token per layer, specialized for train / prefill / decode /
-    speculative-verify calls;
-  * the **paper-ideal** reference reuses ``core.systolic_model`` verbatim:
-    ``fsa_utilization(seq)`` times the array's peak is what FSA achieves
-    on that attention shape per Fig. 11, so ``mfu / ideal`` says how far
-    this host run sits from the paper's own ceiling;
-  * ``MFUMeter`` folds both into a ``repro.obs`` registry as per-phase
-    gauges (``model_flops_per_s``, ``mfu``, ``paper_ideal_utilization``,
-    ``mfu_vs_paper_ideal``) and a cumulative FLOPs counter.
-
-On this CPU container the absolute MFU is of course minuscule — the point
-is the plumbing: the same meter pointed at a real array reads directly in
-the paper's units.
+The paper's FSA array stays here as a reference for the paper
+reproduction: ``paper_ideal_flops_per_s`` is what FSA achieves on an
+attention shape per Fig. 11 (``core.systolic_model``).
 """
 
 from __future__ import annotations
@@ -27,14 +21,17 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import systolic_model
+from .peaks import device_peak
 
 __all__ = [
     "ArrayConfig",
     "PAPER_ARRAY",
+    "matmul_param_count",
     "train_step_flops",
     "prefill_flops",
     "decode_flops",
@@ -46,8 +43,9 @@ __all__ = [
 
 @dataclasses.dataclass(frozen=True)
 class ArrayConfig:
-    """The systolic array the MFU denominator refers to (paper Table 1:
-    N = 128 at 1.5 GHz; ``tune.DesignPoint`` uses the same defaults)."""
+    """The paper's systolic array, the reference of
+    ``paper_ideal_flops_per_s`` (Table 1: N = 128 at 1.5 GHz;
+    ``tune.DesignPoint`` uses the same defaults)."""
 
     array_n: int = 128
     freq_ghz: float = 1.5
@@ -67,47 +65,49 @@ PAPER_ARRAY = ArrayConfig()
 # ---------------------------------------------------------------------------
 
 
-def _attn_flops_per_token(cfg: ModelConfig, context: float) -> float:
-    """Score + value matmul FLOPs for one query token attending over
-    ``context`` keys: 2 * (QK^T) + 2 * (PV) per head per layer."""
-    return 4.0 * context * cfg.resolved_head_dim * cfg.num_heads * cfg.num_layers
+def matmul_param_count(cfg: ModelConfig) -> int:
+    """Active parameters that enter a matmul for each token: all but the
+    embedding lookup.  A tied table is still the output head's matmul."""
+    lookup = 0 if cfg.tie_embeddings else cfg.vocab_size * cfg.d_model
+    return cfg.active_param_count() - lookup
+
+
+def _attn_flops(cfg: ModelConfig, pairs: float) -> float:
+    """Score and value matmul FLOPs over ``pairs`` query-key pairs:
+    2 * (QK^T) + 2 * (PV) per head per layer."""
+    return 4.0 * pairs * cfg.resolved_head_dim * cfg.num_heads * cfg.num_layers
+
+
+def _causal_pairs(n: float) -> float:
+    return n * (n + 1) / 2.0
 
 
 def train_step_flops(cfg: ModelConfig, batch: int, seq_len: int) -> float:
     """One optimizer step over ``batch`` sequences of ``seq_len`` tokens:
-    6 FLOPs per active param per token (fwd 2 + bwd 4), plus the causal
-    attention term (mean context seq/2) at 3x forward cost."""
-    tokens = float(batch) * seq_len
-    param = 6.0 * cfg.active_param_count() * tokens
-    attn = 3.0 * _attn_flops_per_token(cfg, seq_len / 2.0) * tokens
-    return param + attn
+    3x the causal forward (forward 1 + backward 2)."""
+    return 3.0 * batch * prefill_flops(cfg, seq_len)
 
 
 def prefill_flops(cfg: ModelConfig, prompt_len: int) -> float:
     """Forward over one prompt (causal: token i attends to i+1 keys)."""
-    param = 2.0 * cfg.active_param_count() * prompt_len
-    attn = _attn_flops_per_token(cfg, (prompt_len + 1) / 2.0) * prompt_len
-    return param + attn
+    param = 2.0 * matmul_param_count(cfg) * prompt_len
+    return param + _attn_flops(cfg, _causal_pairs(prompt_len))
 
 
 def decode_flops(cfg: ModelConfig, contexts) -> float:
     """One batched decode step; ``contexts`` = per-live-slot KV lengths."""
     contexts = np.asarray(contexts, dtype=np.float64)
-    n = float(contexts.size)
-    param = 2.0 * cfg.active_param_count() * n
-    attn = sum(_attn_flops_per_token(cfg, c + 1.0) for c in contexts)
-    return param + attn
+    param = 2.0 * matmul_param_count(cfg) * float(contexts.size)
+    return param + _attn_flops(cfg, float(np.sum(contexts + 1.0)))
 
 
 def verify_flops(cfg: ModelConfig, contexts, k: int) -> float:
     """One speculative verify: K+1 teacher-forced tokens per slot, each
     attending over its (growing) context."""
-    total = 0.0
-    for c in np.asarray(contexts, dtype=np.float64):
-        for j in range(k + 1):
-            total += _attn_flops_per_token(cfg, c + j + 1.0)
-    param = 2.0 * cfg.active_param_count() * float(len(contexts)) * (k + 1)
-    return param + total
+    contexts = np.asarray(contexts, dtype=np.float64)
+    pairs = float(np.sum(contexts)) * (k + 1) + contexts.size * _causal_pairs(k + 1)
+    param = 2.0 * matmul_param_count(cfg) * float(contexts.size) * (k + 1)
+    return param + _attn_flops(cfg, pairs)
 
 
 def paper_ideal_flops_per_s(
@@ -127,15 +127,22 @@ def paper_ideal_flops_per_s(
 class MFUMeter:
     """Per-phase MFU gauges on a ``repro.obs`` registry.
 
-    ``record(phase, flops, seconds, seq_len=...)`` computes achieved
-    FLOPs/s, divides by the array peak (-> MFU, the Fig. 11 y-axis), and —
-    when the phase has a characteristic attention length — also reports
-    the paper-ideal utilization at that length and the achieved/ideal
-    ratio.  Returns the computed record as a plain dict."""
+    ``record(phase, flops, seconds)`` computes achieved FLOPs/s and divides
+    it by the peak of ``chips`` devices: ``peak_flops_per_s`` per device
+    when given, else the bf16 peak of the first device's kind.  Where
+    neither is known the meter counts FLOPs but sets no ``mfu`` gauge and
+    records ``mfu`` as None.  Returns the computed record as a plain dict."""
 
     def __init__(self, cfg: ModelConfig, registry, *,
-                 array: ArrayConfig = PAPER_ARRAY, prefix: str = ""):
-        self.cfg, self.array = cfg, array
+                 peak_flops_per_s: Optional[float] = None, chips: int = 1,
+                 prefix: str = ""):
+        if peak_flops_per_s is None:
+            peak = device_peak(jax.devices()[0].device_kind)
+            peak_flops_per_s = peak["bf16_flops_per_s"] if peak else None
+        self.cfg = cfg
+        self.peak_flops_per_s = (
+            peak_flops_per_s * chips if peak_flops_per_s else None
+        )
         p = prefix
         self.registry = registry
         self._flops_total = registry.counter(
@@ -147,71 +154,32 @@ class MFUMeter:
         )
         self._mfu = registry.gauge(
             p + "mfu",
-            "model FLOPs utilization vs the FSA array peak "
-            f"({array.peak_flops_per_s / 1e12:.3f} TFLOP/s)",
+            "model FLOPs utilization vs the devices' bf16 peak "
+            f"({self.peak_flops_per_s / 1e12:.3f} TFLOP/s)",
             ("phase",),
-        )
-        self._ideal = registry.gauge(
-            p + "paper_ideal_utilization",
-            "Fig. 11 FSA utilization at this phase's attention length",
-            ("phase",),
-        )
-        self._vs_ideal = registry.gauge(
-            p + "mfu_vs_paper_ideal",
-            "achieved utilization / paper-ideal FSA utilization",
-            ("phase",),
-        )
+        ) if self.peak_flops_per_s else None
 
-    def record(self, phase: str, flops: float, seconds: float, *,
-               seq_len: Optional[int] = None) -> dict:
+    def record(self, phase: str, flops: float, seconds: float) -> dict:
         seconds = max(float(seconds), 1e-12)
         fps = flops / seconds
-        mfu = fps / self.array.peak_flops_per_s
         self._flops_total.labels(phase=phase).inc(flops)
         self._flops_per_s.labels(phase=phase).set(fps)
-        self._mfu.labels(phase=phase).set(mfu)
-        rec = {"phase": phase, "flops": flops, "flops_per_s": fps, "mfu": mfu}
-        if seq_len is not None and seq_len >= 1:
-            ideal = systolic_model.fsa_utilization(
-                int(seq_len), self.cfg.resolved_head_dim, self.array.array_n,
-                single_direction=self.array.single_direction,
-            ) if self.cfg.resolved_head_dim == self.array.array_n else (
-                # The closed form maps Bc = N_ROWS = d; for other head dims
-                # report utilization at the paper's head_dim instead.
-                systolic_model.fsa_utilization(
-                    int(seq_len), self.array.array_n, self.array.array_n,
-                    single_direction=self.array.single_direction,
-                )
-            )
-            self._ideal.labels(phase=phase).set(ideal)
-            self._vs_ideal.labels(phase=phase).set(mfu / ideal)
-            rec.update(paper_ideal_utilization=ideal, mfu_vs_paper_ideal=mfu / ideal)
-        return rec
+        mfu = None
+        if self._mfu is not None:
+            mfu = fps / self.peak_flops_per_s
+            self._mfu.labels(phase=phase).set(mfu)
+        return {"phase": phase, "flops": flops, "flops_per_s": fps, "mfu": mfu}
 
     # -- phase-specific conveniences ---------------------------------------
 
     def train_step(self, batch: int, seq_len: int, seconds: float) -> dict:
-        return self.record(
-            "train", train_step_flops(self.cfg, batch, seq_len), seconds,
-            seq_len=seq_len,
-        )
+        return self.record("train", train_step_flops(self.cfg, batch, seq_len), seconds)
 
     def prefill(self, prompt_len: int, seconds: float) -> dict:
-        return self.record(
-            "prefill", prefill_flops(self.cfg, prompt_len), seconds,
-            seq_len=prompt_len,
-        )
+        return self.record("prefill", prefill_flops(self.cfg, prompt_len), seconds)
 
     def decode(self, contexts, seconds: float) -> dict:
-        ctx = np.asarray(contexts)
-        seq = int(ctx.mean()) + 1 if ctx.size else None
-        return self.record(
-            "decode", decode_flops(self.cfg, contexts), seconds, seq_len=seq
-        )
+        return self.record("decode", decode_flops(self.cfg, contexts), seconds)
 
     def verify(self, contexts, k: int, seconds: float) -> dict:
-        ctx = np.asarray(contexts)
-        seq = int(ctx.mean()) + k + 1 if ctx.size else None
-        return self.record(
-            "verify", verify_flops(self.cfg, contexts, k), seconds, seq_len=seq
-        )
+        return self.record("verify", verify_flops(self.cfg, contexts, k), seconds)

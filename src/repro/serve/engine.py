@@ -31,7 +31,7 @@ Telemetry (``repro.obs``): every engine owns a metrics ``Registry`` —
 request-lifecycle histograms (``serve_ttft_seconds``,
 ``serve_tpot_seconds``, ``serve_queue_wait_seconds``), slot-occupancy /
 batch-utilization / queue-depth gauges, per-phase jit-executable gauges,
-spec acceptance, and per-phase MFU gauges against the paper's FSA array
+spec acceptance, and per-phase MFU gauges against the devices' bf16 peak
 (``repro.obs.mfu``).  The legacy ``stats`` dict is now a property over the
 registry counters.  With a real ``Tracer`` installed (``--trace-out``),
 phases emit live spans and each retired request leaves queued/prefill/
@@ -141,7 +141,9 @@ class ServeEngine:
         # the free NullTracer unless a launcher installed a real Tracer.
         self.registry = registry if registry is not None else Registry()
         self.tracer = tracer if tracer is not None else get_tracer()
-        self.mfu = MFUMeter(cfg, self.registry)
+        self.mfu = MFUMeter(
+            cfg, self.registry, chips=mesh.devices.size if mesh is not None else 1
+        )
         self._stat_keys = ["prefill_calls", "insert_calls", "decode_steps"]
         self._counters = {
             k: self.registry.counter(f"serve_{k}_total", h)

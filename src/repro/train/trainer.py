@@ -8,10 +8,15 @@ from latest, watchdog thresholds, preemption drain) is the multi-host one.
 Telemetry (``repro.obs``): each step lands in the trainer's metrics
 registry (``train_steps_total``/``train_tokens_total`` counters,
 ``train_step_seconds`` histogram, loss/grad-norm gauges, per-step MFU
-against the paper's FSA array) and, when ``TrainerConfig.metrics_jsonl``
+against the devices' bf16 peak) and, when ``TrainerConfig.metrics_jsonl``
 is set, as one structured JSONL record per step — the stream
 ``launch/scrape_log.py`` now parses without regexes.  The human log line
-is kept.  Spans go to the ambient tracer (``--trace-out`` installs one).
+is kept.  Spans go to the ambient tracer (``--trace-out`` installs one):
+each step is ``train_step.data`` (the batch read and copied to the
+device), ``train_step`` (dispatch until the loss is ready) and
+``train_step.readback`` (loss and grad norm read back, metrics, JSONL
+line, hooks).  A real ``Tracer`` passes them to the profiler; the default
+``NullTracer`` makes no call.
 """
 
 from __future__ import annotations
@@ -83,7 +88,9 @@ class Trainer:
         self.preempt = PreemptionHandler(install=False, registry=self.registry)
         self.hooks = hooks or {}
         self.mesh = mesh
-        self.mfu = MFUMeter(cfg, self.registry)
+        self.mfu = MFUMeter(
+            cfg, self.registry, chips=mesh.devices.size if mesh is not None else 1
+        )
         self._steps_total = self.registry.counter(
             "train_steps_total", "optimizer steps completed"
         )
@@ -172,7 +179,10 @@ class Trainer:
                 self.ckpt.save(state["step"], {k: state[k] for k in ckpt_keys})
                 break
             step = state["step"]
-            batch = {k: jnp.asarray(v) for k, v in self.data.batch(step).items()}
+            with self.tracer.span("train_step.data", cat="train", tid=0):
+                batch = {
+                    k: jnp.asarray(v) for k, v in self.data.batch(step).items()
+                }
             self.watchdog.start_step()
             with mesh_context(self.mesh), self.tracer.span(
                 "train_step", cat="train", tid=0, args={"step": step}
@@ -193,32 +203,33 @@ class Trainer:
                 jax.block_until_ready(metrics["loss"])
             dur = self.watchdog.end_step()
             state = new_state
-            loss = float(metrics["loss"])
-            gnorm = float(metrics["grad_norm"])
-            losses.append(loss)
-            self._steps_total.inc()
-            self._tokens_total.inc(tokens_per_batch)
-            self._h_step.observe(dur)
-            self._g_loss.set(loss)
-            self._g_gnorm.set(gnorm)
-            self._g_tok_s.set(tokens_per_batch / dur)
-            mfu_rec = self.mfu.train_step(
-                self.shape.global_batch, self.shape.seq_len, dur
-            )
-            if jsonl is not None:
-                jsonl.write(json.dumps({
-                    "event": "train_step",
-                    "step": step + 1,
-                    "loss": loss,
-                    "grad_norm": gnorm,
-                    "step_s": dur,
-                    "tokens_per_s": tokens_per_batch / dur,
-                    "mfu": mfu_rec["mfu"],
-                    "model_flops_per_s": mfu_rec["flops_per_s"],
-                }) + "\n")
-                jsonl.flush()
-            if "on_step" in self.hooks:
-                self.hooks["on_step"](state, metrics)
+            with self.tracer.span("train_step.readback", cat="train", tid=0):
+                loss = float(metrics["loss"])
+                gnorm = float(metrics["grad_norm"])
+                losses.append(loss)
+                self._steps_total.inc()
+                self._tokens_total.inc(tokens_per_batch)
+                self._h_step.observe(dur)
+                self._g_loss.set(loss)
+                self._g_gnorm.set(gnorm)
+                self._g_tok_s.set(tokens_per_batch / dur)
+                mfu_rec = self.mfu.train_step(
+                    self.shape.global_batch, self.shape.seq_len, dur
+                )
+                if jsonl is not None:
+                    jsonl.write(json.dumps({
+                        "event": "train_step",
+                        "step": step + 1,
+                        "loss": loss,
+                        "grad_norm": gnorm,
+                        "step_s": dur,
+                        "tokens_per_s": tokens_per_batch / dur,
+                        "mfu": mfu_rec["mfu"],
+                        "model_flops_per_s": mfu_rec["flops_per_s"],
+                    }) + "\n")
+                    jsonl.flush()
+                if "on_step" in self.hooks:
+                    self.hooks["on_step"](state, metrics)
             if (step + 1) % self.tcfg.log_every == 0:
                 print(
                     f"step {step + 1} loss {loss:.4f} "

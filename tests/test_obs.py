@@ -2,10 +2,11 @@
 
 Covers: histogram/percentile math vs numpy, Prometheus/JSON exposition
 golden output, Chrome-trace schema validity, the disabled-mode no-op
-overhead guard, MFU cross-checks against ``core.systolic_model`` at the
-paper point, engine TTFT/TPOT plausibility, the library compile counter
-vs the ``jit_recompiles`` fixture, fault-layer counters, trainer metrics
-+ the JSONL stream round-trip through ``launch/scrape_log``.
+overhead guard, MFU against the device peak (and the paper-ideal
+reference against ``core.systolic_model``), engine TTFT/TPOT plausibility,
+the library compile counter vs the ``jit_recompiles`` fixture, fault-layer
+counters, trainer metrics, step-phase spans + the JSONL stream round-trip
+through ``launch/scrape_log``.
 """
 
 import os
@@ -15,6 +16,7 @@ if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "")
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     )
 
+import dataclasses  # noqa: E402
 import json  # noqa: E402
 import time  # noqa: E402
 
@@ -28,15 +30,19 @@ from repro.dist.fault import PreemptionHandler, StepWatchdog  # noqa: E402
 from repro.launch.scrape_log import scrape, scrape_dryrun  # noqa: E402
 from repro.models import init_params  # noqa: E402
 from repro.obs import (  # noqa: E402
-    MFUMeter,
     PAPER_ARRAY,
+    PEAKS,
+    MFUMeter,
+    NullTracer,
     Registry,
     Tracer,
     decode_flops,
+    matmul_param_count,
     paper_ideal_flops_per_s,
     prefill_flops,
     set_enabled,
     train_step_flops,
+    verify_flops,
     watch_jit_compiles,
 )
 from repro.serve.engine import Request, ServeEngine  # noqa: E402
@@ -56,6 +62,16 @@ TINY = ModelConfig(
     dtype="float32",
     remat=False,
 )
+
+TEST_PEAK = 1e12
+
+
+@pytest.fixture
+def cpu_peak(monkeypatch):
+    """The CPU has no entry in the peak table: tests that read an MFU gauge
+    of the engine or the trainer give it one."""
+    monkeypatch.setitem(PEAKS, jax.devices()[0].device_kind,
+                        {"bf16_flops_per_s": TEST_PEAK, "hbm_bytes_per_s": 1e11})
 
 
 @pytest.fixture(autouse=True)
@@ -212,7 +228,7 @@ def test_chrome_trace_schema_valid(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# MFU vs systolic_model at the paper point
+# MFU against the device peak; the paper-ideal reference vs systolic_model
 # ---------------------------------------------------------------------------
 
 
@@ -227,31 +243,65 @@ def test_paper_ideal_matches_systolic_model():
 
 
 def test_mfu_meter_achieving_ideal_reads_one():
-    """If a phase achieves exactly the paper-ideal FLOPs/s, the
-    achieved/ideal gauge must read 1 (and mfu == Fig. 11 utilization)."""
-    cfg = ModelConfig(
-        name="hd128", family="dense", num_layers=1, d_model=128,
-        num_heads=1, num_kv_heads=1, head_dim=128, d_ff=256,
-        vocab_size=256, dtype="float32", remat=False,
-    )
+    """MFU = flops / (seconds x peak): a phase that achieves exactly the
+    given peak reads 1, half of it 0.5, and the gauge keeps the last."""
     reg = Registry()
-    meter = MFUMeter(cfg, reg)
-    seq = 4096
-    flops = 1e12
-    seconds = flops / paper_ideal_flops_per_s(seq)
-    rec = meter.record("prefill", flops, seconds, seq_len=seq)
-    assert rec["mfu_vs_paper_ideal"] == pytest.approx(1.0)
-    assert rec["mfu"] == pytest.approx(systolic_model.fsa_utilization(seq, 128))
-    assert reg.get("mfu").labels(phase="prefill").value == pytest.approx(rec["mfu"])
+    meter = MFUMeter(TINY, reg, peak_flops_per_s=TEST_PEAK)
+    flops = 3e12
+    rec = meter.record("prefill", flops, flops / TEST_PEAK)
+    assert rec["mfu"] == pytest.approx(1.0)
+    rec = meter.record("prefill", flops, 2 * flops / TEST_PEAK)
+    assert rec["mfu"] == pytest.approx(0.5)
+    assert rec["flops_per_s"] == pytest.approx(TEST_PEAK / 2)
+    assert reg.get("mfu").labels(phase="prefill").value == pytest.approx(0.5)
+    # Over a mesh the peak is the devices' together.
+    four = MFUMeter(TINY, Registry(), peak_flops_per_s=TEST_PEAK, chips=4)
+    assert four.record("train", flops, flops / TEST_PEAK)["mfu"] == pytest.approx(0.25)
+
+
+def test_mfu_meter_on_a_device_without_a_peak_sets_no_gauge():
+    """The CPU is not in the peak table: FLOPs are counted, no MFU."""
+    assert jax.devices()[0].device_kind not in PEAKS
+    reg = Registry()
+    rec = MFUMeter(TINY, reg).record("train", 1e9, 1.0)
+    assert rec["mfu"] is None and rec["flops_per_s"] == pytest.approx(1e9)
+    assert reg.get("mfu") is None
+    assert reg.get("model_flops_total").labels(phase="train").value == 1e9
+
+
+def test_one_peak_table_for_mfu_and_the_dry_run_roofline():
+    from repro.launch import roofline
+
+    v5e = PEAKS["TPU v5 lite"]
+    assert v5e == {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+    assert roofline.PEAK_FLOPS == v5e["bf16_flops_per_s"]
+    assert roofline.HBM_BW == v5e["hbm_bytes_per_s"]
 
 
 def test_flops_closed_forms_scale_sanely():
     # Param term dominates at tiny context; attention term grows with ctx.
-    p = TINY.active_param_count()
-    assert prefill_flops(TINY, 8) > 2.0 * p * 8
+    m = matmul_param_count(TINY)
+    assert prefill_flops(TINY, 8) > 2.0 * m * 8
     assert decode_flops(TINY, [16, 16]) > decode_flops(TINY, [4, 4])
-    # Train: 3x the forward cost on params (6 vs 2 FLOPs/param/token).
-    assert train_step_flops(TINY, 2, 32) > 3 * prefill_flops(TINY, 32)
+    # Train: 3x the causal forward of each sequence (remat not counted).
+    assert train_step_flops(TINY, 2, 32) == pytest.approx(2 * 3 * prefill_flops(TINY, 32))
+    # A verify of K+1 tokens is K+1 decode steps at growing contexts.
+    ctx = np.array([5, 9])
+    assert verify_flops(TINY, ctx, 2) == pytest.approx(
+        sum(decode_flops(TINY, ctx + j) for j in range(3)))
+
+
+def test_train_flops_count_matmul_parameters_and_causal_pairs():
+    """Matmul parameters (the tied head yes, the embedding lookup no),
+    causal pairs, 3x the forward."""
+    d, L, v, ff, h, kv, hd, seq = 64, 2, 128, 128, 4, 2, 16, 32
+    attn = d * h * hd * 2 + d * kv * hd * 2
+    layers = L * (attn + 3 * d * ff)
+    assert matmul_param_count(TINY) == layers + v * d  # untied: lookup left out
+    tied = dataclasses.replace(TINY, tie_embeddings=True)
+    assert matmul_param_count(tied) == layers + v * d  # the head's matmul
+    want = 3 * (2 * (layers + v * d) * seq + 4 * hd * h * L * seq * (seq + 1) // 2)
+    assert train_step_flops(TINY, 1, seq) == pytest.approx(want)
 
 
 # ---------------------------------------------------------------------------
@@ -281,7 +331,7 @@ def _run_wave(params, n_requests=5, max_new=4, tracer=None):
     return eng, done
 
 
-def test_engine_ttft_tpot_plausible(tiny_params):
+def test_engine_ttft_tpot_plausible(tiny_params, cpu_peak):
     eng, done = _run_wave(tiny_params)
     ttft = eng.registry.get("serve_ttft_seconds")
     tpot = eng.registry.get("serve_tpot_seconds")
@@ -323,7 +373,7 @@ def test_engine_stats_property_backwards_compatible(tiny_params):
     assert eng.stats == before
 
 
-def test_engine_prometheus_dump_has_required_series(tiny_params):
+def test_engine_prometheus_dump_has_required_series(tiny_params, cpu_peak):
     eng, _ = _run_wave(tiny_params, n_requests=3)
     eng.compile_counts()
     prom = eng.registry.to_prometheus()
@@ -394,7 +444,7 @@ def test_fault_counters():
     assert reg.get("preemptions_total").value == 1
 
 
-def test_trainer_metrics_and_jsonl_roundtrip(tmp_path):
+def test_trainer_metrics_and_jsonl_roundtrip(tmp_path, cpu_peak):
     jsonl = tmp_path / "train.metrics.jsonl"
     tcfg = TrainerConfig(
         total_steps=4, ckpt_every=100, ckpt_dir=str(tmp_path / "ckpt"),
@@ -426,6 +476,50 @@ def test_trainer_metrics_and_jsonl_roundtrip(tmp_path):
     # Interleaved human log lines don't confuse the fast path.
     noisy = "step 1 loss 5.0 gnorm 1.0 3 ms\n" + text + "not json {\n"
     assert scrape(noisy) == records
+
+
+def _annotations(monkeypatch):
+    """Names passed to the profiler's TraceAnnotation, in order of entry."""
+    from repro.obs import trace as trace_mod
+
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            entered.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(trace_mod.jax.profiler, "TraceAnnotation", Annotation)
+    return entered
+
+
+def test_trainer_step_phase_spans(tmp_path, monkeypatch):
+    """With a Tracer each step is data, step, readback, in that order, on
+    the Tracer and the profiler alike; with the NullTracer the trainer
+    makes no profiler call."""
+    entered = _annotations(monkeypatch)
+    phases = ["train_step.data", "train_step", "train_step.readback"]
+
+    def trainer(tracer, sub):
+        tcfg = TrainerConfig(total_steps=2, ckpt_every=100,
+                             ckpt_dir=str(tmp_path / sub), log_every=100)
+        return Trainer(TINY, ShapeConfig("t", 16, 2, "train"), tcfg, tracer=tracer)
+
+    trainer(NullTracer(), "null").run()
+    assert entered == []
+
+    tr = Tracer()
+    trainer(tr, "traced").run()
+    assert entered == phases * 2
+    spans = sorted((e for e in tr.events if e["ph"] == "X"), key=lambda e: e["ts"])
+    assert [e["name"] for e in spans] == phases * 2
+    for a, b in zip(spans, spans[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"] + 1e-3  # one after another
 
 
 def test_scrape_regex_fallback_still_works():

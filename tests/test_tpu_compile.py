@@ -10,6 +10,7 @@ process may load the TPU library, and every test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -105,6 +106,24 @@ CASES = {
 def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
     fn, args = CASES[case](one_chip)
     _compile(fn, *args)
+
+
+# The kernel's tag in a custom call's frontend attributes; the compiler
+# prints the metadata over several lines.
+TAG = re.compile(r'kernel_metadata=\{\s*"kernel"\s*:\s*"(\w+)"\s*\}')
+
+
+def test_training_gradient_carries_the_kernel_tags(one_chip, no_compile_cache):
+    """Each Mosaic call of the training gradient names its kernel, so a
+    device trace can tell them apart without guessing from shapes."""
+    fn, args = CASES["train_grad"](one_chip)
+    text = _compile(fn, *args)
+    # One instruction per line once the tags are on one line each; the
+    # tuple elements of a call carry its attributes too.
+    lines = TAG.sub(lambda m: f'kernel_metadata={{"kernel":"{m[1]}"}}', text).splitlines()
+    calls = [line for line in lines if 'custom_call_target="tpu_custom_call"' in line]
+    tags = [TAG.search(line)[1] for line in calls]
+    assert sorted(tags) == ["flash_dkv", "flash_dq", "flash_fwd"]
 
 
 def test_kernel_under_mesh_compiles_for_four_chips(topo, no_compile_cache):
