@@ -15,7 +15,13 @@ kernel whose S/P tiles never leave VMEM:
     scores), preserving the paper's numerics claims;
   * optionally the 8-segment PWL exp2 (paper §3.3) computed with the same
     slope/intercept MAC formulation, on the VPU;
-  * GQA without materializing repeated KV heads (index_map arithmetic).
+  * GQA without materializing repeated KV heads (index_map arithmetic);
+  * causal grid steps whose (q block, KV block) pair lies wholly above the
+    diagonal do no work and fetch nothing: the body sits under
+    ``pl.when(live)`` and the KV index_map clamps a dead step to the row's
+    last live block, which is already in VMEM.  The skip is exact: such a
+    block would add ``exp2(-huge) = 0`` to ``l``, ``0 @ V`` to the
+    accumulator and leave ``m`` as it was.
 
 The backward pass has its own Pallas kernels (kernel_bwd.py): the forward
 optionally emits base-2 log-sum-exp rows, and FlashAttention-2-style dq /
@@ -39,6 +45,35 @@ NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
 LANES = 128  # TPU vector lane width: row statistics are stored lane-broadcast
+
+
+def last_live_kv_block(i, block_q, block_k, q_offset):
+    """Last KV block that q block ``i`` sees under the causal mask."""
+    return (i * block_q + q_offset + block_q - 1) // block_k
+
+
+def first_live_q_block(j, block_q, block_k, q_offset):
+    """First q block that sees KV block ``j`` under the causal mask."""
+    return (j * block_k - q_offset) // block_q
+
+
+def causal_grid_steps(sq, sk, block_q, block_k, q_offset, causal):
+    """(live, total) grid steps per head of a flash grid over (q, KV) blocks.
+
+    Block ``(i, j)`` is live iff some row ``i*block_q + q_offset + r`` may
+    attend some column ``j*block_k + c``; non-causal calls are all live.
+    The forward, dq and dkv grids skip the same steps.
+    """
+    block_q, block_k = min(block_q, sq), min(block_k, sk)
+    num_q, num_k = -(-sq // block_q), -(-sk // block_k)
+    total = num_q * num_k
+    if not causal:
+        return total, total
+    live = sum(
+        min(last_live_kv_block(i, block_q, block_k, q_offset) + 1, num_k)
+        for i in range(num_q)
+    )
+    return live, total
 
 
 def _exp2_inline(x: jax.Array, exp2_impl: str, num_segments: int) -> jax.Array:
@@ -91,41 +126,45 @@ def _fwd_kernel(
 
     c = sm_scale * LOG2_E  # folded scale (Algorithm 1 lines 10/12)
 
-    # Causal: whole KV blocks strictly above the diagonal contribute nothing;
-    # keep the arithmetic but mask (grid steps still run — masked lanes).
-    q = q_ref[0].astype(jnp.float32)  # [bq, d]
-    k = k_ref[0].astype(jnp.float32)  # [bk, d]
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )  # [bq, bk] — unscaled S, as in Algorithm 1 line 6
+    # Causal: KV blocks wholly above the diagonal add exact zeros, so their
+    # steps do nothing; they come after every live block of the row.
+    live = j <= last_live_kv_block(i, block_q, block_k, q_offset) if causal else True
 
-    cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    if seq_k % block_k != 0:
-        s = jnp.where(cols < seq_k, s, NEG_INF)
-    if causal:
-        rows = (
-            i * block_q
-            + q_offset
-            + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    @pl.when(live)
+    def _step():
+        q = q_ref[0].astype(jnp.float32)  # [bq, d]
+        k = k_ref[0].astype(jnp.float32)  # [bk, d]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )  # [bq, bk] — unscaled S, as in Algorithm 1 line 6
+
+        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        if seq_k % block_k != 0:
+            s = jnp.where(cols < seq_k, s, NEG_INF)
+        if causal:
+            rows = (
+                i * block_q
+                + q_offset
+                + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+            )
+            s = jnp.where(rows >= cols, s, NEG_INF)
+
+        old_m = m_scr[...]
+        local_m = jnp.max(s, axis=-1)
+        new_m = jnp.maximum(local_m, old_m)                      # line 8
+        b = _exp2_inline(c * (old_m - new_m), exp2_impl, num_segments)  # line 10
+        p = _exp2_inline(c * (s - new_m[:, None]), exp2_impl, num_segments)  # line 12
+        l_scr[...] = l_scr[...] * b + jnp.sum(p, axis=-1)        # lines 13-14
+        v = v_ref[0].astype(jnp.float32)
+        # Both products run at contract precision fp32: P stays fp32, as in
+        # the decode einsum that must agree with this kernel.
+        local_o = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
         )
-        s = jnp.where(rows >= cols, s, NEG_INF)
-
-    old_m = m_scr[...]
-    local_m = jnp.max(s, axis=-1)
-    new_m = jnp.maximum(local_m, old_m)                      # line 8
-    b = _exp2_inline(c * (old_m - new_m), exp2_impl, num_segments)  # line 10
-    p = _exp2_inline(c * (s - new_m[:, None]), exp2_impl, num_segments)  # line 12
-    l_scr[...] = l_scr[...] * b + jnp.sum(p, axis=-1)        # lines 13-14
-    v = v_ref[0].astype(jnp.float32)
-    # Both products run at contract precision fp32: P stays fp32, as in
-    # the decode einsum that must agree with this kernel.
-    local_o = jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    acc_scr[...] = acc_scr[...] * b[:, None] + local_o       # line 16
-    m_scr[...] = new_m
+        acc_scr[...] = acc_scr[...] * b[:, None] + local_o       # line 16
+        m_scr[...] = new_m
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():  # line 21: O_i = diag(l)^-1 O
@@ -138,6 +177,30 @@ def _fwd_kernel(
             # lane-broadcast: a [block_q] row block is not (8, 128)-tiled.
             lse = c * m_scr[...] + jnp.log2(safe_l)
             lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
+
+
+def kv_block_index(causal, block_q, block_k, q_offset):
+    """KV block a (q block i, KV block j) step reads, KV innermost.
+
+    A dead causal step names the row's last live block, the one the step
+    before it read, so the pipeline issues no copy for it.
+    """
+    if not causal:
+        return lambda i, j: j
+    return lambda i, j: jnp.minimum(j, last_live_kv_block(i, block_q, block_k, q_offset))
+
+
+def q_block_index(causal, block_q, block_k, q_offset, num_q):
+    """q block a (KV block j, q block i) step reads, q innermost.
+
+    A dead causal step names the column's first live q block, the one the
+    step after it reads, so the pipeline issues no copy for it.
+    """
+    if not causal:
+        return lambda j, i: i
+    return lambda j, i: jnp.minimum(
+        jnp.maximum(i, first_live_q_block(j, block_q, block_k, q_offset)), num_q - 1
+    )
 
 
 def flash_attention_fwd(
@@ -195,11 +258,14 @@ def flash_attention_fwd(
         seq_k=sk,
     )
 
+    kv_block = kv_block_index(causal, block_q, block_k, q_offset)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
         # GQA: map q-head bh -> kv-head bh // rep without materializing.
-        pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
-        pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda bh, i, j, rep=rep: (bh // rep, kv_block(i, j), 0)),
+        pl.BlockSpec((1, block_k, d),
+                     lambda bh, i, j, rep=rep: (bh // rep, kv_block(i, j), 0)),
     ]
 
     out = pl.pallas_call(

@@ -12,7 +12,12 @@ kernels:
     cheap reshape-sum) so no grid step ever writes another step's block.
 
 All matmul work uses fp32 accumulation; masks are additive [Bq, Bk]
-biases as in the forward.
+biases as in the forward.  As in the forward, causal steps whose block
+pair lies wholly above the diagonal do no work and fetch nothing: the dq
+grid meets them at the end of each KV sweep and names the row's last live
+KV block, the dkv grid at the start of each q sweep and names the column's
+first live q block.  The skip is exact: there P = exp2(-huge - LSE) = 0,
+so dS = 0 and every accumulator would gain exact zeros.
 """
 
 from __future__ import annotations
@@ -27,7 +32,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.pwl_exp2 import LOG2_E
-from .kernel import LANES
+from .kernel import (
+    LANES, first_live_q_block, kv_block_index, last_live_kv_block, q_block_index,
+)
 
 NEG_INF = -1e30
 
@@ -57,22 +64,26 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, acc,
         acc[...] = jnp.zeros_like(acc)
 
     c = sm_scale * LOG2_E
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, :1]      # [bq, 1] (stored lane-broadcast)
-    delta = delta_ref[0][:, :1]  # [bq, 1] = rowsum(dO * O)
+    live = j <= last_live_kv_block(i, block_q, block_k, q_offset) if causal else True
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s + _mask_bias(i, j, block_q, block_k, causal, q_offset, seq_k, pad_k)
-    p = jnp.exp2(c * s - lse)  # recompute (never stored)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * sm_scale
-    acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+    @pl.when(live)
+    def _():
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0][:, :1]      # [bq, 1] (stored lane-broadcast)
+        delta = delta_ref[0][:, :1]  # [bq, 1] = rowsum(dO * O)
+
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s + _mask_bias(i, j, block_q, block_k, causal, q_offset, seq_k, pad_k)
+        p = jnp.exp2(c * s - lse)  # recompute (never stored)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * sm_scale
+        acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
+                                        preferred_element_type=jnp.float32)
 
     @pl.when(j == num_k_blocks - 1)
     def _():
@@ -92,24 +103,28 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     c = sm_scale * LOG2_E
-    q = q_ref[0].astype(jnp.float32)
-    k = k_ref[0].astype(jnp.float32)
-    v = v_ref[0].astype(jnp.float32)
-    do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0][:, :1]
-    delta = delta_ref[0][:, :1]
+    live = i >= first_live_q_block(j, block_q, block_k, q_offset) if causal else True
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s + _mask_bias(i, j, block_q, block_k, causal, q_offset, seq_k, pad_k)
-    p = jnp.exp2(c * s - lse)  # [bq, bk]
-    dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
-    dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    ds = p * (dp - delta) * sm_scale  # [bq, bk]
-    dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                       preferred_element_type=jnp.float32)
+    @pl.when(live)
+    def _():
+        q = q_ref[0].astype(jnp.float32)
+        k = k_ref[0].astype(jnp.float32)
+        v = v_ref[0].astype(jnp.float32)
+        do = do_ref[0].astype(jnp.float32)
+        lse = lse_ref[0][:, :1]
+        delta = delta_ref[0][:, :1]
+
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        s = s + _mask_bias(i, j, block_q, block_k, causal, q_offset, seq_k, pad_k)
+        p = jnp.exp2(c * s - lse)  # [bq, bk]
+        dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                                           preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta) * sm_scale  # [bq, bk]
+        dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                           preferred_element_type=jnp.float32)
 
     @pl.when(i == num_q_blocks - 1)
     def _():
@@ -167,13 +182,16 @@ def flash_attention_bwd(
                   sm_scale=float(scale), q_offset=q_offset, seq_k=sk,
                   pad_k=pad_k)
 
+    kv_block = kv_block_index(causal, block_q, block_k, q_offset)
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, num_k_blocks=num_k, **common),
         grid=(batch * h, num_q, num_k),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, i, j, rep=rep: (bh // rep, j, 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda bh, i, j, rep=rep: (bh // rep, kv_block(i, j), 0)),
+            pl.BlockSpec((1, block_k, d),
+                         lambda bh, i, j, rep=rep: (bh // rep, kv_block(i, j), 0)),
             pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_q, LANES), lambda bh, i, j: (bh, i, 0)),
             pl.BlockSpec((1, block_q, LANES), lambda bh, i, j: (bh, i, 0)),
@@ -187,16 +205,17 @@ def flash_attention_bwd(
     )(qh, kh, vh, doh, lse, delta)
 
     # dk/dv at q-head granularity; sum the rep partials afterwards.
+    q_block = q_block_index(causal, block_q, block_k, q_offset, num_q)
     dk_p, dv_p = pl.pallas_call(
         functools.partial(_dkv_kernel, num_q_blocks=num_q, **common),
         grid=(batch * h, num_k, num_q),
         in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, q_block(j, i), 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, j, i, rep=rep: (bh // rep, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, j, i, rep=rep: (bh // rep, j, 0)),
-            pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda bh, j, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_q, LANES), lambda bh, j, i: (bh, i, 0)),
+            pl.BlockSpec((1, block_q, d), lambda bh, j, i: (bh, q_block(j, i), 0)),
+            pl.BlockSpec((1, block_q, LANES), lambda bh, j, i: (bh, q_block(j, i), 0)),
+            pl.BlockSpec((1, block_q, LANES), lambda bh, j, i: (bh, q_block(j, i), 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, j, i: (bh, j, 0)),

@@ -8,7 +8,9 @@ from latest, watchdog thresholds, preemption drain) is the multi-host one.
 Telemetry (``repro.obs``): each step lands in the trainer's metrics
 registry (``train_steps_total``/``train_tokens_total`` counters,
 ``train_step_seconds`` histogram, loss/grad-norm gauges, per-step MFU
-against the devices' bf16 peak) and, when ``TrainerConfig.metrics_jsonl``
+against the devices' bf16 peak; ``flash_live_block_share``, set once, is
+the share of the flash grids' steps that the causal skip leaves live at
+the training length) and, when ``TrainerConfig.metrics_jsonl``
 is set, as one structured JSONL record per step — the stream
 ``launch/scrape_log.py`` now parses without regexes.  The human log line
 is kept.  Spans go to the ambient tracer (``--trace-out`` installs one):
@@ -35,6 +37,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.data import DataConfig, make_source
 from repro.dist.collectives import mesh_context
 from repro.dist.fault import PreemptionHandler, StepWatchdog
+from repro.kernels.flash_attention.kernel import causal_grid_steps
 from repro.models import init_params, lm_loss
 from repro.obs import MFUMeter, Registry, get_tracer
 from repro.optim import make_optimizer
@@ -107,6 +110,14 @@ class Trainer:
         self._g_tok_s = self.registry.gauge(
             "train_tokens_per_s", "throughput of the last step"
         )
+        live, total = causal_grid_steps(
+            shape.seq_len, shape.seq_len, cfg.attn_block_q, cfg.attn_block_k,
+            0, cfg.causal,
+        )
+        self.registry.gauge(
+            "flash_live_block_share",
+            "share of flash grid steps that do work at the training length",
+        ).set(live / total)
 
         sched = cosine_with_warmup(tcfg.peak_lr, tcfg.warmup_steps, tcfg.total_steps)
         self.optimizer = make_optimizer(tcfg.optimizer, lr=sched)

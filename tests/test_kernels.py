@@ -238,3 +238,112 @@ def test_pallas_path_without_interpret_raises_off_tpu():
     q = _rand((1, 128, 2, 32), 0)
     with pytest.raises(ValueError, match="interpret"):
         flash_attention(q, q, q, True, None, 0, 64, 64, "exact", 8, "pallas")
+
+
+# -- Causal block skip -------------------------------------------------------
+#
+# A causal grid step whose (q block, KV block) pair lies wholly above the
+# diagonal does no work and reads nothing new.  NaN planted in inputs that
+# only such steps read shows it: a step that still ran would turn its exact
+# zero contribution into NaN (0 * NaN), so the outputs that only dead steps
+# touch stay finite, and equal the clean call's bit for bit.  Without the
+# causal mask every step is live and the poison must reach them.
+
+from repro.kernels.flash_attention.kernel import causal_grid_steps  # noqa: E402
+
+SKIP_BLOCK = 32
+SKIP_CASES = {
+    # (B, Sq, Sk, H, Hkv, d, q_offset)
+    "square": (1, 128, 128, 2, 2, 16, 0),
+    "ragged_offset": (1, 72, 128, 2, 2, 16, 40),
+    "padded_k": (1, 100, 100, 2, 2, 16, 0),
+    "gqa": (1, 128, 128, 4, 1, 16, 0),
+    "kv_past_queries": (1, 64, 128, 2, 2, 16, 0),
+}
+
+
+def _skip_inputs(case):
+    b, sq, sk, h, hkv, d, _ = case
+    return (_rand((b, sq, h, d), 0), _rand((b, sk, hkv, d), 1),
+            _rand((b, sk, hkv, d), 2), _rand((b, sq, h, d), 3))
+
+
+def _block_rows(blocks, size, n):
+    """Row indices below ``n`` of the given blocks of ``size`` rows."""
+    rows = np.concatenate([np.arange(i * size, (i + 1) * size) for i in blocks])
+    return rows[rows < n]
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("name", sorted(SKIP_CASES))
+@pytest.mark.parametrize("grid", ["fwd", "dq", "dkv"])
+def test_causal_dead_blocks_are_skipped(grid, name, causal):
+    case = SKIP_CASES[name]
+    _, sq, sk, _, _, _, qo = case
+    bq = bk = SKIP_BLOCK
+    num_q, num_k = -(-sq // bq), -(-sk // bk)
+    q, k, v, do = _skip_inputs(case)
+    kw = dict(causal=causal, q_offset=qo, block_q=bq, block_k=bk, interpret=True)
+
+    if grid in ("fwd", "dq"):
+        # Poison the last KV block; check the q blocks whose causal rows
+        # all end before it.
+        poisoned = slice((num_k - 1) * bk, sk)
+        blocks = [i for i in range(num_q) if (i * bq + qo + bq - 1) // bk < num_k - 1]
+        rows = _block_rows(blocks, bq, sq)
+    else:
+        # Poison the first q block; check the KV blocks past its last row.
+        poisoned = slice(0, bq)
+        blocks = [j for j in range(num_k) if j * bk > qo + bq - 1]
+        rows = _block_rows(blocks, bk, sk)
+    assert rows.size, "the case must have outputs that only dead steps touch"
+
+    def run(q, k, v, do):
+        if grid == "fwd":
+            return (flash_attention_fwd(q, k, v, **kw),)
+        o, lse = flash_attention_fwd(*_skip_inputs(case)[:3], **kw, return_lse=True)
+        dq, dk, dv = flash_attention_bwd(q, k, v, o, lse, do, **kw)
+        return (dq,) if grid == "dq" else (dk, dv)
+
+    if grid == "fwd":
+        v = v.at[:, poisoned].set(jnp.nan)
+    elif grid == "dq":
+        k = k.at[:, poisoned].set(jnp.nan)
+        v = v.at[:, poisoned].set(jnp.nan)
+    else:
+        q = q.at[:, poisoned].set(jnp.nan)
+        do = do.at[:, poisoned].set(jnp.nan)
+    got = run(q, k, v, do)
+    clean = run(*_skip_inputs(case))
+
+    for g, c in zip(got, clean):
+        g = np.take(np.asarray(g), rows, axis=1)
+        c = np.take(np.asarray(c), rows, axis=1)
+        if causal:
+            assert np.isfinite(g).all()
+            assert g.tobytes() == c.tobytes()
+        else:
+            assert np.isnan(g).all()
+
+
+def test_causal_grid_steps_matches_brute_force():
+    def brute(sq, sk, bq, bk, qo, causal):
+        bq, bk = min(bq, sq), min(bk, sk)
+        num_q, num_k = -(-sq // bq), -(-sk // bk)
+        live = 0
+        for i in range(num_q):
+            rows = i * bq + qo + np.arange(bq)
+            for j in range(num_k):
+                cols = j * bk + np.arange(bk)
+                live += (not causal) or bool((rows[:, None] >= cols[None, :]).any())
+        return live, num_q * num_k
+
+    for sq, sk in [(128, 128), (100, 100), (72, 128), (64, 256), (256, 96), (1, 17)]:
+        for bq, bk in [(32, 32), (32, 64), (64, 16)]:
+            for qo in (0, 5, 40, 128):
+                for causal in (True, False):
+                    args = (sq, sk, bq, bk, qo, causal)
+                    assert causal_grid_steps(*args) == brute(*args), args
+
+    assert causal_grid_steps(2048, 2048, 128, 128, 0, True) == (136, 256)
+    assert causal_grid_steps(2048, 2048, 128, 128, 0, False) == (256, 256)

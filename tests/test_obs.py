@@ -444,6 +444,16 @@ def test_fault_counters():
     assert reg.get("preemptions_total").value == 1
 
 
+@pytest.mark.parametrize("causal, share", [(True, 136 / 256), (False, 1.0)])
+def test_trainer_sets_flash_live_block_share(tmp_path, causal, share):
+    """Set once at construction from the training length and the flash
+    blocks: 136 of 16 x 16 block pairs are live under a causal mask."""
+    cfg = dataclasses.replace(TINY, causal=causal)
+    tcfg = TrainerConfig(total_steps=1, ckpt_dir=str(tmp_path))
+    t = Trainer(cfg, ShapeConfig("t", 2048, 1, "train"), tcfg)
+    assert t.registry.get("flash_live_block_share").value == share
+
+
 def test_trainer_metrics_and_jsonl_roundtrip(tmp_path, cpu_peak):
     jsonl = tmp_path / "train.metrics.jsonl"
     tcfg = TrainerConfig(

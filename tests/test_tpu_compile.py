@@ -113,17 +113,36 @@ def test_kernel_compiles_for_v5e(case, one_chip, no_compile_cache):
 TAG = re.compile(r'kernel_metadata=\{\s*"kernel"\s*:\s*"(\w+)"\s*\}')
 
 
+# The shapes of a custom call's first two operands, as its layout
+# constraints print them.
+FIRST_TWO_OPERANDS = re.compile(
+    r"operand_layout_constraints=\{(\w+\[[\d,]*\])\{[^}]*\}, (\w+\[[\d,]*\])")
+
+
+def _mosaic_calls(one_chip):
+    """The training gradient's Mosaic call lines, one instruction each once
+    the tags are on one line each; the tuple elements of a call carry its
+    attributes too."""
+    fn, args = CASES["train_grad"](one_chip)
+    text = _compile(fn, *args)
+    lines = TAG.sub(lambda m: f'kernel_metadata={{"kernel":"{m[1]}"}}', text).splitlines()
+    return [line for line in lines if 'custom_call_target="tpu_custom_call"' in line]
+
+
 def test_training_gradient_carries_the_kernel_tags(one_chip, no_compile_cache):
     """Each Mosaic call of the training gradient names its kernel, so a
     device trace can tell them apart without guessing from shapes."""
-    fn, args = CASES["train_grad"](one_chip)
-    text = _compile(fn, *args)
-    # One instruction per line once the tags are on one line each; the
-    # tuple elements of a call carry its attributes too.
-    lines = TAG.sub(lambda m: f'kernel_metadata={{"kernel":"{m[1]}"}}', text).splitlines()
-    calls = [line for line in lines if 'custom_call_target="tpu_custom_call"' in line]
-    tags = [TAG.search(line)[1] for line in calls]
+    tags = [TAG.search(line)[1] for line in _mosaic_calls(one_chip)]
     assert sorted(tags) == ["flash_dkv", "flash_dq", "flash_fwd"]
+
+
+def test_flash_calls_take_head_major_q_and_k_first(one_chip, no_compile_cache):
+    """Every flash call of the training gradient takes q and k head-major,
+    ``[heads x batch, seq, head_dim]``, as its first two operands: a device
+    trace reads each call's shapes from them."""
+    head_major = f"bf16[{H * B},{S},{D}]"
+    for line in _mosaic_calls(one_chip):
+        assert FIRST_TWO_OPERANDS.search(line).groups() == (head_major, head_major), line
 
 
 def test_kernel_under_mesh_compiles_for_four_chips(topo, no_compile_cache):
