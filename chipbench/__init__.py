@@ -1,4 +1,4 @@
-"""Chip benchmark of the training path on a TPU.
+"""Chip benchmark of the training and serving paths on a TPU.
 
 ``python3 -m chipbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>``
 runs one cell of ``BENCHMARK.json`` (one model configuration under one

@@ -74,3 +74,18 @@ def train_flops(arch: dict, seq: int) -> int:
     under remat is not counted."""
     layers, head = matmul_params(arch)
     return 3 * (2 * (layers + head) * seq + attention_flops(arch, seq))
+
+
+def prefill_flops(arch: dict, n: int) -> int:
+    """Model operations of one prefill of n true prompt tokens: the layers'
+    matmuls for every token, the head once (for the token that is sampled)
+    and causal attention.  Padding to a bucket is not work."""
+    layers, head = matmul_params(arch)
+    return 2 * layers * n + 2 * head + attention_flops(arch, n)
+
+
+def decode_flops(arch: dict, keys: int) -> int:
+    """Model operations of one decode token that attends to ``keys`` cached
+    keys (itself included): the layers' matmuls, the head, and attention."""
+    layers, head = matmul_params(arch)
+    return 2 * (layers + head) + 4 * arch["head_dim"] * arch["heads"] * arch["layers"] * keys

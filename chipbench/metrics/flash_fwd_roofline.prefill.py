@@ -1,0 +1,45 @@
+"""Roofline share of the flash forward kernel in serving's prefill: for the
+prefill programs (``jit__prefill``) that ran wholly in the traced window,
+the roofline time of one causal forward per layer at each request's true
+prompt length, over the device time of the ``flash_fwd``-tagged calls
+inside those programs.  Programs are matched in order to the prefills that
+the cell recorded; a program whose calls read another bucket than the
+recorded one is an error.  Counting true lengths, not buckets, means that
+less padding or more skipped blocks shows as a gain.  A program without
+the tags reads nothing.
+
+The program's name comes from the engine's private ``_prefill`` function:
+where the cell recorded prefills in the traced steps and no program of that
+name ran, the reader raises rather than go quiet."""
+
+from chipbench import counts, trace
+from chipbench import kernel_tags as kt
+
+PREFILL = r"^jit__prefill\("
+
+
+def read(run):
+    progs = trace.module_events(run.trace, PREFILL)
+    in_steps = run.cell.traced(run.trace)["prefills"]
+    if not progs:
+        if in_steps:
+            raise ValueError(f"{len(in_steps)} prefills recorded in the traced steps, "
+                             f"no program matches {PREFILL!r}")
+        return None
+    fwd = kt.tagged(run.trace).get("flash_fwd", [])
+    if not fwd:
+        return None
+    recorded = run.cell.prefills
+    if len(progs) > len(recorded):
+        raise ValueError(f"{len(progs)} prefill programs traced, {len(recorded)} recorded")
+    a, need, took = run.arch, 0.0, 0.0
+    for prog, (n, bucket) in zip(sorted(progs, key=lambda e: e.start), recorded):
+        calls = trace.inside(fwd, [prog])
+        if not calls:
+            raise ValueError(f"a prefill program without flash_fwd calls at {prog.start}")
+        if max(kt.flash_shape(run, e)[2] for e in calls) != bucket:
+            raise ValueError(f"prefill of {n} tokens: flash calls do not read bucket {bucket}")
+        flops, nbytes = counts.flash_fwd(1, n, n, a["heads"], a["kv_heads"], a["head_dim"])
+        need += a["layers"] * counts.roofline_s(flops, nbytes, run.peak)[0]
+        took += kt.device_s(calls)
+    return 100.0 * need / took

@@ -1,6 +1,6 @@
 """The system under test, as the benchmark builds it: the program's model
 configuration from a configuration file, and its parameter layout.  Only
-this module and the cells (`train.py`) import the program."""
+this module and the cells (`train.py`, `serve.py`) import the program."""
 
 from __future__ import annotations
 

@@ -12,7 +12,8 @@ with code 2 and prints no result.
 
 ``--plant`` is for measuring the check itself and is never used by a
 benchmark run: ``control`` puts the fp8 control's readings in the program's
-place, and ``unchanged`` and ``half_batch`` break the timed path.
+place, and ``unchanged``, ``half_batch`` and ``token`` break the timed path
+(each cell says how, and refuses a plant that it does not implement).
 """
 
 from __future__ import annotations
@@ -153,7 +154,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--plant", default="none",
-                    choices=("none", "control", "unchanged", "half_batch"))
+                    choices=("none", "control", "unchanged", "half_batch", "token"))
     args = ap.parse_args(argv)
 
     try:

@@ -18,8 +18,9 @@ import re
 
 WINDOW_SPAN = "chipbench.window"
 # Host spans that name what the host was doing: the harness's own, and the
-# program's (repro.obs Tracer spans pass through to the profiler).
-HOST_SPANS = ("chipbench.", "train_step")
+# program's (repro.obs Tracer spans pass through to the profiler): the
+# trainer's step phases and the serving engine's live phases.
+HOST_SPANS = ("chipbench.", "train_step", "prefill", "generate")
 
 
 

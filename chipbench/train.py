@@ -17,11 +17,14 @@ import numpy as np
 from . import program, reference, traffic, weights
 
 CHECK_STEPS = 3
+PLANTS = ("none", "control", "unchanged", "half_batch")
 
 
 class TrainCell:
     def __init__(self, conf: dict, arch: dict, mix: dict, *, tracing: bool = False,
                  plant: str = "none"):
+        if plant not in PLANTS:
+            raise ValueError(f"the training cell plants none of {plant!r}")
         self.conf, self.arch, self.mix = conf, arch, mix
         self.tracing, self.plant = tracing, plant
         self.tmp = tempfile.mkdtemp(prefix="chipbench-train-")

@@ -68,3 +68,25 @@ def test_unknown_device_is_an_error():
 
     with pytest.raises(ValueError):
         peaks.peaks("cpu")
+
+
+YI16 = dict(layers=16, d_model=4096, heads=32, kv_heads=4, head_dim=128, d_ff=11008,
+            vocab=64000)
+
+
+def test_prefill_counts_true_tokens_and_the_head_once():
+    layers, head = counts.matmul_params(YI16)
+    assert layers == 16 * (2 * 4096 * 4096 + 2 * 4096 * 512 + 3 * 4096 * 11008)
+    assert head == 64000 * 4096
+    n = 1536
+    attn = 4 * 128 * 32 * 16 * n * (n + 1) // 2
+    assert counts.prefill_flops(YI16, n) == 2 * layers * n + 2 * head + attn
+    # About 5.74 GFLOP a prompt token at the median prompt.
+    assert abs(counts.prefill_flops(YI16, n) / n - 5.738e9) < 0.001e9
+
+
+def test_decode_counts_one_token_over_its_keys():
+    layers, head = counts.matmul_params(YI16)
+    assert counts.decode_flops(YI16, 1) == 2 * (layers + head) + 4 * 128 * 32 * 16
+    step = counts.decode_flops(YI16, 2000) - counts.decode_flops(YI16, 1999)
+    assert step == 4 * 128 * 32 * 16

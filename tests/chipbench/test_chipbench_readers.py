@@ -1,8 +1,10 @@
 """The training cell's per-layer readers on a synthetic trace: the flash
 roofline takes its work from the model and the steps in the window, and
-only its time from the kernels' events; a Mosaic kernel that is not the
-flash kernel, or flash calls at shapes that do not fit the model, are
-errors; with no step in the window a reader reads nothing."""
+only its time from the kernels' tagged events; a Mosaic kernel that is not
+a flash kernel is left out, and flash calls at shapes that do not fit the
+model are errors; with no step in the window a reader reads nothing.  The
+tags give the same calls, and so the same number, as the signatures that
+the reader matched before the kernels were tagged."""
 
 import sys
 from pathlib import Path
@@ -21,13 +23,16 @@ Q, KV = f"bf16[{BH},256,64]{{2,1,0}}", f"bf16[{BKV},256,64]{{2,1,0}}"
 LSE = f"f32[{BH},256,128]{{2,1,0}}"
 
 
-def call(results, operands):
+def call(results, operands, tag=None):
     ops = ", ".join(f"{o} %x{i}" for i, o in enumerate(operands))
-    return f'%k = {results} custom-call({ops}), custom_call_target="tpu_custom_call"'
+    meta = f'{{\n"kernel":"{tag}"\n}}' if tag else "{}"
+    return (f'%k = {results} custom-call({ops}), custom_call_target="tpu_custom_call", '
+            f"frontend_attributes={{kernel_metadata={meta}}}")
 
 
-FWD, DQ = call(f"({Q}, {LSE})", [Q, KV, KV]), call(Q, [Q, KV, KV, Q, LSE, LSE])
-DKV = call(f"({Q}, {Q})", [Q, KV, KV, Q, LSE, LSE])
+FWD = call(f"({Q}, {LSE})", [Q, KV, KV], "flash_fwd")
+DQ = call(Q, [Q, KV, KV, Q, LSE, LSE], "flash_dq")
+DKV = call(f"({Q}, {Q})", [Q, KV, KV, Q, LSE, LSE], "flash_dkv")
 
 
 def ev(name, start, end):
@@ -63,16 +68,43 @@ def test_flash_roofline_counts_the_model_work_and_the_kernels_time():
 
 
 def test_flash_roofline_refuses_another_mosaic_kernel():
-    other = ev(call("bf16[16,128]{1,0}", ["bf16[16,128]{1,0}"]), 900, 950)
-    with pytest.raises(ValueError, match="signature"):
-        spec.reader("flash_roofline.train")(run_of(step_ops(0) + [other]))
+    """Another Mosaic kernel, tagged or not, is no longer refused: it is
+    left out, and the reading is the one without it."""
+    read = spec.reader("flash_roofline.train")
+    want = read(run_of(step_ops(0)))
+    for tag in (None, "pwl_exp2"):
+        other = ev(call("bf16[16,128]{1,0}", ["bf16[16,128]{1,0}"], tag), 900, 950)
+        assert read(run_of(step_ops(0) + [other])) == pytest.approx(want)
 
 
 def test_flash_roofline_refuses_calls_that_do_not_fit_the_model():
     q8 = f"bf16[{BH},256,128]{{2,1,0}}"
     k8 = f"bf16[{BKV},256,128]{{2,1,0}}"
     with pytest.raises(ValueError, match="fit"):
-        spec.reader("flash_roofline.train")(run_of([ev(call(q8, [q8, k8, k8]), 0, 100)]))
+        spec.reader("flash_roofline.train")(
+            run_of([ev(call(q8, [q8, k8, k8], "flash_fwd"), 0, 100)]))
+
+
+def _by_signature(run):
+    """The reading as it was taken before the kernels were tagged: the
+    flash calls found by their signature (``trace.kernel_kind``)."""
+    steps = trace.module_events(run.trace, r"^jit_train_step\(")
+    ev_ = trace.kernel_events(run.trace)
+    flash = trace.inside(ev_.get("flash_fwd", []) + ev_.get("flash_dq", [])
+                         + ev_.get("flash_dkv", []), steps)
+    shape = (MIX["batch"], MIX["seq_len"], MIX["seq_len"], 4, 2, 64)
+    p = peaks.peaks("TPU v5 lite")
+    t_fwd, _ = counts.roofline_s(*counts.flash_fwd(*shape, with_lse=True), p)
+    t_bwd, _ = counts.roofline_s(*counts.flash_bwd(*shape), p)
+    return 100.0 * len(steps) * ARCH["layers"] * (t_fwd + t_bwd) / (sum(e.dur for e in flash) / 1e9)
+
+
+def test_tags_and_signatures_find_the_same_calls():
+    # Two steps of unequal call times, and a call outside every step.
+    ops = step_ops(0) + [ev(k.name, k.start_ns + 1000, k.start_ns + 1000 + 37 * (i + 1))
+                         for i, k in enumerate(step_ops(0))] + [ev(FWD, 2100, 2400)]
+    run = run_of(ops)
+    assert spec.reader("flash_roofline.train")(run) == pytest.approx(_by_signature(run), rel=1e-12)
 
 
 @pytest.mark.parametrize("metric", ["flash_roofline.train", "mfu.train"])
