@@ -21,6 +21,18 @@ class MoEConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (arXiv:2309.00071) as transformers'
+    ``_compute_yarn_parameters`` reads it from a ``rope_parameters`` group."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class SSMConfig:
     state_dim: int = 64
     head_dim: int = 64
@@ -61,6 +73,16 @@ class ModelConfig:
     ssm: Optional[SSMConfig] = None
     xlstm: Optional[XLSTMConfig] = None
     attn_every: int = 0  # zamba2: shared attention block every N ssm layers
+    # Layer kinds in the order they repeat, "full" or "sliding"; the layer
+    # scan runs one period per step (models.model).  None: one kind, set by
+    # ``sliding_window`` and ``rope_yarn`` alone.
+    layer_pattern: Optional[tuple[str, ...]] = None
+    # Keys each query sees, itself included, in "sliding" layers (every
+    # layer where there is no pattern); None: full causal attention.
+    sliding_window: Optional[int] = None
+    # YaRN scaling of the "full" layers' rope (every layer where there is
+    # no pattern); sliding layers keep plain rope at ``rope_theta``.
+    rope_yarn: Optional[YarnConfig] = None
     # Execution knobs
     parallelism: str = "tp"  # tp (Megatron TP+DP+SP) | dp_only (pure DP+ZeRO)
     # None: by platform (models.attention._impl_attention) — the compiled
@@ -93,6 +115,16 @@ class ModelConfig:
         if self.family == "ssm":
             return self.num_layers // 2  # (mLSTM, sLSTM) pairs
         return self.num_layers
+
+    def layer_config(self, kind: str) -> "ModelConfig":
+        """The single-kind config that one layer of ``kind`` runs under."""
+        if kind not in ("full", "sliding"):
+            raise ValueError(f"unknown layer kind {kind!r}")
+        return dataclasses.replace(
+            self, layer_pattern=None,
+            sliding_window=self.sliding_window if kind == "sliding" else None,
+            rope_yarn=self.rope_yarn if kind == "full" else None,
+        )
 
     @property
     def resolved_head_dim(self) -> int:

@@ -34,6 +34,8 @@ _MODULES = {
     "qwen2-vl-7b": "qwen2_vl_7b",
     "zamba2-1.2b": "zamba2_1_2b",
     "xlstm-125m": "xlstm_125m",
+    # Served on the chip; not one of the dry-run grid's ARCH_IDS.
+    "mellum2-12b-a2.5b": "mellum2_12b",
 }
 
 

@@ -55,6 +55,7 @@ def _attend_single(
     q_offset: int | jax.Array = 0,
     bias: Optional[jax.Array] = None,  # [Sq, Sk]
     unroll: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """One (batch, head) slice of Algorithm 1.  fp32 state, tiled KV scan."""
     sq, d = q.shape
@@ -102,6 +103,8 @@ def _attend_single(
             if causal:
                 rows = i * block_q + q_offset + jnp.arange(block_q)[:, None]
                 s = s + jnp.where(rows >= cols, 0.0, NEG_INF)
+                if window is not None:
+                    s = s + jnp.where(rows - cols < window, 0.0, NEG_INF)
 
             # lines 7-9: rowmax, running max, a = old_m - new_m
             local_m = jnp.max(s, axis=-1)
@@ -151,6 +154,7 @@ def systolic_attention(
     q_offset: int | jax.Array = 0,
     bias: Optional[jax.Array] = None,
     unroll: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Batched multi-head SystolicAttention (GQA-aware).
 
@@ -163,6 +167,8 @@ def systolic_attention(
       q_offset: absolute position of q[0] (for decode/chunked prefill
         causal masking against a longer KV).
       bias: optional additive attention bias broadcastable to [Sq, Sk].
+      window: with ``causal``, query row r (q_offset included) sees only
+        keys r - window < c <= r (a sliding window of ``window`` keys).
     """
     b_, sq, h, d = q.shape
     hkv = k.shape[2]
@@ -185,6 +191,7 @@ def systolic_attention(
         q_offset=q_offset,
         bias=bias,
         unroll=unroll,
+        window=window,
     )
     # GQA without materializing repeated KV: vmap q's rep dim with KV
     # broadcast (in_axes=None), then over kv-heads, then batch.
@@ -211,8 +218,10 @@ def naive_attention(
     q_offset: int | jax.Array = 0,
     bias: Optional[jax.Array] = None,
     dtype: jnp.dtype = jnp.float32,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Materialized-softmax oracle (the ref implementation for all kernels)."""
+    """Materialized-softmax oracle (the ref implementation for all kernels);
+    ``window`` as in ``systolic_attention``."""
     b_, sq, h, d = q.shape
     hkv = k.shape[2]
     rep = h // hkv
@@ -227,6 +236,8 @@ def naive_attention(
         rows = q_offset + jnp.arange(sq)[:, None]
         cols = jnp.arange(k.shape[1])[None, :]
         s = jnp.where(rows >= cols, s, -jnp.inf)
+        if window is not None:
+            s = jnp.where(rows - cols < window, s, -jnp.inf)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhqk,bkhd->bqhd", p, vr.astype(dtype))
     return o.astype(q.dtype)

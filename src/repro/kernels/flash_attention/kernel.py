@@ -21,7 +21,11 @@ kernel whose S/P tiles never leave VMEM:
     ``pl.when(live)`` and the KV index_map clamps a dead step to the row's
     last live block, which is already in VMEM.  The skip is exact: such a
     block would add ``exp2(-huge) = 0`` to ``l``, ``0 @ V`` to the
-    accumulator and leave ``m`` as it was.
+    accumulator and leave ``m`` as it was;
+  * with a sliding ``window`` the KV axis of the grid covers only the
+    blocks a window can reach: step ``j`` of q block ``i`` reads KV block
+    ``first_window_kv_block(i) + j``, so the blocks below the band never
+    iterate, and the lower edge is masked per element.
 
 The backward pass has its own Pallas kernels (kernel_bwd.py): the forward
 optionally emits base-2 log-sum-exp rows, and FlashAttention-2-style dq /
@@ -55,6 +59,18 @@ def last_live_kv_block(i, block_q, block_k, q_offset):
 def first_live_q_block(j, block_q, block_k, q_offset):
     """First q block that sees KV block ``j`` under the causal mask."""
     return (j * block_k - q_offset) // block_q
+
+
+def first_window_kv_block(i, block_q, block_k, q_offset, window):
+    """First KV block that q block ``i`` sees under a window of ``window``
+    keys (row ``r`` sees columns ``r - window < c <= r``)."""
+    return jnp.maximum(i * block_q + q_offset - window + 1, 0) // block_k
+
+
+def window_kv_steps(window, block_q, block_k, num_k):
+    """KV steps of a windowed grid: the most KV blocks one q block's band,
+    ``window + block_q - 1`` columns wide, can touch."""
+    return min(num_k, -(-(window + block_q - 1) // block_k) + 1)
 
 
 def causal_grid_steps(sq, sk, block_q, block_k, q_offset, causal):
@@ -99,7 +115,7 @@ def _fwd_kernel(
     k_ref,  # [1, block_k, d]
     v_ref,  # [1, block_k, d]
     *refs,  # o_ref, [lse_ref], m_scr, l_scr, acc_scr
-    num_k_blocks: int,
+    num_k_blocks: int,  # KV steps of the grid
     block_q: int,
     block_k: int,
     causal: bool,
@@ -109,6 +125,8 @@ def _fwd_kernel(
     num_segments: int,
     seq_k: int,
     with_lse: bool,
+    window: Optional[int] = None,
+    num_kv: int = 0,  # KV blocks of the input (windowed grids)
 ):
     if with_lse:
         o_ref, lse_ref, m_scr, l_scr, acc_scr = refs
@@ -128,7 +146,14 @@ def _fwd_kernel(
 
     # Causal: KV blocks wholly above the diagonal add exact zeros, so their
     # steps do nothing; they come after every live block of the row.
-    live = j <= last_live_kv_block(i, block_q, block_k, q_offset) if causal else True
+    if window is None:
+        kb = j
+        live = j <= last_live_kv_block(i, block_q, block_k, q_offset) if causal else True
+    else:
+        # Windowed: step j reads the j-th block of the row's band.
+        kb = first_window_kv_block(i, block_q, block_k, q_offset, window) + j
+        last = jnp.minimum(last_live_kv_block(i, block_q, block_k, q_offset), num_kv - 1)
+        live = kb <= last
 
     @pl.when(live)
     def _step():
@@ -139,7 +164,7 @@ def _fwd_kernel(
             precision=jax.lax.Precision.HIGHEST,
         )  # [bq, bk] — unscaled S, as in Algorithm 1 line 6
 
-        cols = j * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+        cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
         if seq_k % block_k != 0:
             s = jnp.where(cols < seq_k, s, NEG_INF)
         if causal:
@@ -149,6 +174,8 @@ def _fwd_kernel(
                 + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
             )
             s = jnp.where(rows >= cols, s, NEG_INF)
+            if window is not None:
+                s = jnp.where(rows - cols < window, s, NEG_INF)
 
         old_m = m_scr[...]
         local_m = jnp.max(s, axis=-1)
@@ -190,6 +217,18 @@ def kv_block_index(causal, block_q, block_k, q_offset):
     return lambda i, j: jnp.minimum(j, last_live_kv_block(i, block_q, block_k, q_offset))
 
 
+def window_kv_block_index(block_q, block_k, q_offset, window, num_k):
+    """KV block a windowed (q block i, step j) step reads: the j-th block of
+    the row's band, clamped to its last live block as ``kv_block_index``
+    clamps a dead causal step."""
+    def index(i, j):
+        last = jnp.minimum(last_live_kv_block(i, block_q, block_k, q_offset), num_k - 1)
+        return jnp.minimum(first_window_kv_block(i, block_q, block_k, q_offset, window) + j,
+                           last)
+
+    return index
+
+
 def q_block_index(causal, block_q, block_k, q_offset, num_q):
     """q block a (KV block j, q block i) step reads, q innermost.
 
@@ -217,7 +256,11 @@ def flash_attention_fwd(
     num_segments: int = 8,
     interpret: bool = False,
     return_lse: bool = False,
+    window: Optional[int] = None,
 ):
+    """``window``: each query row ``r`` (``q_offset`` included) sees keys
+    ``r - window < c <= r``; needs ``causal``.  Windowed calls carry the
+    tag ``flash_fwd_window``."""
     batch, sq, h, d = q.shape
     _, sk, hkv, _ = k.shape
     assert h % hkv == 0
@@ -242,12 +285,20 @@ def flash_attention_fwd(
         kh = jnp.pad(kh, ((0, 0), (0, pad_k), (0, 0)))
         vh = jnp.pad(vh, ((0, 0), (0, pad_k), (0, 0)))
 
-    grid = (batch * h, num_q, num_k)
+    if window is None:
+        steps, tag = num_k, "flash_fwd"
+        kv_block = kv_block_index(causal, block_q, block_k, q_offset)
+    else:
+        if not causal:
+            raise ValueError("a sliding window needs causal attention")
+        steps, tag = window_kv_steps(window, block_q, block_k, num_k), "flash_fwd_window"
+        kv_block = window_kv_block_index(block_q, block_k, q_offset, window, num_k)
+    grid = (batch * h, num_q, steps)
 
     kernel = functools.partial(
         _fwd_kernel,
         with_lse=return_lse,
-        num_k_blocks=num_k,
+        num_k_blocks=steps,
         block_q=block_q,
         block_k=block_k,
         causal=causal,
@@ -256,9 +307,10 @@ def flash_attention_fwd(
         exp2_impl=exp2_impl,
         num_segments=num_segments,
         seq_k=sk,
+        window=window,
+        num_kv=num_k,
     )
 
-    kv_block = kv_block_index(causal, block_q, block_k, q_offset)
     in_specs = [
         pl.BlockSpec((1, block_q, d), lambda bh, i, j: (bh, i, 0)),
         # GQA: map q-head bh -> kv-head bh // rep without materializing.
@@ -298,8 +350,8 @@ def flash_attention_fwd(
         interpret=interpret,
         # The tag lands in the custom call's frontend attributes
         # (``kernel_metadata``), which device traces print with each call.
-        name="flash_fwd",
-        metadata={"kernel": "flash_fwd"},
+        name=tag,
+        metadata={"kernel": tag},
     )(qh, kh, vh)
 
     if return_lse:

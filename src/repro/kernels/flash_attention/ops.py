@@ -23,18 +23,18 @@ from .kernel_bwd import flash_attention_bwd
 
 
 def _jnp_forward(q, k, v, *, causal, scale, q_offset, block_q, block_k,
-                 exp2_impl, num_segments):
+                 exp2_impl, num_segments, window=None):
     return systolic_attention(
         q, k, v,
         causal=causal, scale=scale, q_offset=q_offset,
         block_q=block_q, block_k=block_k,
-        exp2_impl=exp2_impl, num_segments=num_segments,
+        exp2_impl=exp2_impl, num_segments=num_segments, window=window,
     )
 
 
 @functools.partial(
     jax.custom_vjp,
-    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11),
+    nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 12),
 )
 def flash_attention(
     q: jax.Array,
@@ -49,26 +49,31 @@ def flash_attention(
     num_segments: int = 8,
     impl: str = "jnp",
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
-    """Fused attention, [B,S,H,d] layout, GQA-aware.  Differentiable."""
+    """Fused attention, [B,S,H,d] layout, GQA-aware.  Differentiable, but
+    for a ``window`` under ``impl='pallas'``: the backward grids take no
+    window, so differentiating such a call raises."""
     if impl == "pallas":
         return flash_attention_fwd(
             q, k, v,
             causal=causal, scale=scale, q_offset=q_offset,
             block_q=block_q, block_k=block_k,
             exp2_impl=exp2_impl, num_segments=num_segments,
-            interpret=interpret,
+            interpret=interpret, window=window,
         )
     return _jnp_forward(
         q, k, v,
         causal=causal, scale=scale, q_offset=q_offset,
         block_q=block_q, block_k=block_k,
-        exp2_impl=exp2_impl, num_segments=num_segments,
+        exp2_impl=exp2_impl, num_segments=num_segments, window=window,
     )
 
 
 def _fwd(q, k, v, causal, scale, q_offset, block_q, block_k,
-         exp2_impl, num_segments, impl, interpret):
+         exp2_impl, num_segments, impl, interpret, window):
+    if impl == "pallas" and window is not None:
+        raise NotImplementedError("the flash backward grids take no sliding window")
     if impl == "pallas":
         out, lse = flash_attention_fwd(
             q, k, v,
@@ -82,13 +87,13 @@ def _fwd(q, k, v, causal, scale, q_offset, block_q, block_k,
         q, k, v,
         causal=causal, scale=scale, q_offset=q_offset,
         block_q=block_q, block_k=block_k,
-        exp2_impl=exp2_impl, num_segments=num_segments,
+        exp2_impl=exp2_impl, num_segments=num_segments, window=window,
     )
     return out, (q, k, v, None, None)
 
 
 def _bwd(causal, scale, q_offset, block_q, block_k, exp2_impl,
-         num_segments, impl, interpret, res, g):
+         num_segments, impl, interpret, window, res, g):
     q, k, v, out, lse = res
     if impl == "pallas":
         # FlashAttention-2 backward kernels: P recomputed per VMEM tile
@@ -105,7 +110,7 @@ def _bwd(causal, scale, q_offset, block_q, block_k, exp2_impl,
         _jnp_forward,
         causal=causal, scale=scale, q_offset=q_offset,
         block_q=block_q, block_k=block_k,
-        exp2_impl="exact", num_segments=num_segments,
+        exp2_impl="exact", num_segments=num_segments, window=window,
     )
     _, vjp = jax.vjp(f, q, k, v)
     return vjp(g)

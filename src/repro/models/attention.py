@@ -93,9 +93,17 @@ def _project_qkv(x, params, cfg: ModelConfig, positions):
         q = apply_mrope(q, positions, cfg.mrope_sections, cfg.rope_theta)
         k = apply_mrope(k, positions, cfg.mrope_sections, cfg.rope_theta)
     else:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_yarn)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_yarn)
     return q, k, v
+
+
+def _window(cfg: ModelConfig):
+    """The layer's sliding window (None: full causal).  Attention runs one
+    layer kind at a time: a patterned config is split by ``layer_config``."""
+    if cfg.layer_pattern is not None:
+        raise ValueError("attention takes one layer's config (ModelConfig.layer_config)")
+    return cfg.sliding_window
 
 
 def _impl_attention(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
@@ -107,15 +115,17 @@ def _impl_attention(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
     the serving engine depends on this.
     """
     impl = cfg.attention_impl
+    window = _window(cfg)
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "systolic"
     if impl == "naive":
-        return naive_attention(q, k, v, causal=cfg.causal, q_offset=q_offset)
+        return naive_attention(q, k, v, causal=cfg.causal, q_offset=q_offset, window=window)
     if impl == "pallas":
         def kernel(q, k, v):
             return flash_attention(
                 q, k, v, cfg.causal, None, q_offset,
                 cfg.attn_block_q, cfg.attn_block_k, cfg.exp2_impl, 8, "pallas",
+                False, window,
             )
 
         return map_heads(kernel, q, k, v)
@@ -128,6 +138,7 @@ def _impl_attention(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
         block_k=cfg.attn_block_k,
         exp2_impl=cfg.exp2_impl,
         unroll=cfg.attn_unroll,
+        window=window,
     )
 
 
@@ -282,10 +293,10 @@ def verify_attention(
     qg = q.reshape(b, s_new, cfg.num_kv_heads, rep, hd).astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     s = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k.astype(jnp.float32)) * scale
-    valid = (
-        jnp.arange(k.shape[1])[None, None, None, None, :]
-        <= rows[:, None, None, :, None]
-    )
+    keys = jnp.arange(k.shape[1])[None, None, None, None, :]
+    valid = keys <= rows[:, None, None, :, None]
+    if _window(cfg) is not None:
+        valid &= keys > rows[:, None, None, :, None] - cfg.sliding_window
     s = jnp.where(valid, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = _pv(p, v).astype(x.dtype)
@@ -341,11 +352,13 @@ def decode_attention(
     qg = q.reshape(b, 1, cfg.num_kv_heads, rep, hd).astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
     s = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k.astype(jnp.float32)) * scale
-    # Mask positions beyond each slot's (updated) cache length.
-    valid = (
-        jnp.arange(k.shape[1])[None, None, None, None, :]
-        <= cache.lengths[:, None, None, None, None]
-    )
+    # Mask positions beyond each slot's (updated) cache length, and in a
+    # sliding layer those older than its window; the cache stays dense.
+    keys = jnp.arange(k.shape[1])[None, None, None, None, :]
+    pos = cache.lengths[:, None, None, None, None]
+    valid = keys <= pos
+    if _window(cfg) is not None:
+        valid &= keys > pos - cfg.sliding_window
     s = jnp.where(valid, s, -1e30)
     p = jax.nn.softmax(s, axis=-1)
     o = _pv(p, v).astype(x.dtype)

@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax
@@ -79,16 +80,44 @@ def rope_frequencies(head_dim: int, theta: float) -> np.ndarray:
     return 1.0 / (theta ** (np.arange(0, head_dim, 2, dtype=np.float64) / head_dim))
 
 
+def yarn_frequencies(head_dim: int, theta: float, yarn) -> np.ndarray:
+    """YaRN inverse frequencies (arXiv:2309.00071), as transformers'
+    ``_compute_yarn_parameters`` computes them: dimensions that turn fewer
+    than ``beta_slow`` times over the original context are interpolated
+    (divided by ``factor``), those that turn more than ``beta_fast`` times
+    keep their frequency, and a linear ramp blends the range between (its
+    ends rounded out to whole dimensions, as the published configs have it)."""
+    def correction_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))) / (2 * math.log(theta))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low) / (high - low), 0, 1)
+    inv = rope_frequencies(head_dim, theta)
+    keep = 1.0 - ramp  # 1: extrapolated (kept), 0: interpolated
+    return inv / yarn.factor * (1.0 - keep) + inv * keep
+
+
 def apply_rope(
     x: jax.Array,  # [B, S, H, d]
     positions: jax.Array,  # [B, S]
     theta: float = 10000.0,
+    yarn=None,  # Optional[configs.base.YarnConfig]
 ) -> jax.Array:
     d = x.shape[-1]
-    freqs = jnp.asarray(rope_frequencies(d, theta), jnp.float32)  # [d/2]
+    if yarn is None:
+        inv, mscale = rope_frequencies(d, theta), 1.0
+    else:
+        inv, mscale = yarn_frequencies(d, theta, yarn), yarn.attention_factor
+    freqs = jnp.asarray(inv, jnp.float32)  # [d/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [B, S, d/2]
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if yarn is not None:
+        cos, sin = cos * mscale, sin * mscale
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

@@ -34,7 +34,7 @@ from .attention import (
     verify_attention,
 )
 from .layers import apply_norm, embed_init, mlp_forward, mlp_params, norm_params
-from .moe import moe_forward, moe_params
+from .moe import grouped_dropless, moe_forward, moe_params
 from .ssm import MambaCache, init_mamba_cache, mamba_decode, mamba_forward, mamba_params
 from .xlstm import (
     MLSTMState,
@@ -160,7 +160,11 @@ def _transformer_block(x, layer, cfg: ModelConfig, positions, kv=None, start=0):
     h = apply_norm(x, layer["mlp_norm"], cfg.norm_type)
     quant = get_quant(cfg)
     if cfg.moe is not None:
-        y = moe_forward(h, layer["moe"], cfg)
+        # Prefill into a cache is serving: it routes dropless, as decode
+        # does, where the experts run grouped.  Under EP or an int8 "moe"
+        # policy dropless pads every expert to the token pool, so prefill
+        # keeps capacity routing there.
+        y = moe_forward(h, layer["moe"], cfg, dropless=kv is not None and grouped_dropless(cfg))
         if cfg.moe.dense_residual:
             y = y + mlp_forward(h, layer["dense_mlp"], cfg.mlp_type, quant)
     else:
@@ -177,6 +181,59 @@ def _scan_layers(x, stacked, body, remat: bool, unroll: int = 1):
 
     out, _ = jax.lax.scan(step, x, stacked, unroll=unroll)
     return out
+
+
+def _scan_periods(cfg: ModelConfig, x, layers, body, cache=None, remat: bool = False):
+    """The layer scan of a patterned config: each scan step runs one period
+    of ``p`` layers in order, each under its kind's static config (window,
+    rope), so that flash grids and rope tables stay static.
+
+    Without ``cache``, ``body(layer_cfg, h, layer) -> h``.  With it,
+    ``body(layer_cfg, h, (layer, layer_cache)) -> (h, layer_cache)``, and
+    the stacked cache rides in the carry, each layer's slice updated in
+    place.  Every layer's params and cache are indexed from the stacked
+    ``[L, ...]`` arrays one layer at a time: a period's slice of them as a
+    scan input is materialized whole (3.2 GB of experts for Mellum2's
+    four layers).  Returns ``(h, cache)``.  With ``remat`` each layer is
+    checkpointed, as ``_scan_layers`` does."""
+    kinds = cfg.layer_pattern
+    p = len(kinds)
+    if cfg.num_layers % p:
+        raise ValueError(f"{cfg.num_layers} layers are not whole periods of {kinds}")
+    lcfgs = [cfg.layer_config(k) for k in kinds]
+
+    def at(tree, j):
+        return jax.tree.map(lambda a: jax.lax.dynamic_index_in_dim(a, j, keepdims=False), tree)
+
+    def period(carry, i):
+        h, c = carry
+        for k, lcfg in enumerate(lcfgs):
+            fn = functools.partial(body, lcfg)
+            if remat:
+                fn = jax.checkpoint(fn)
+            j = i * p + k
+            if c is None:
+                h = fn(h, at(layers, j))
+                continue
+            h, kv = fn(h, (at(layers, j), at(c, j)))
+            c = jax.tree.map(lambda a, u: jax.lax.dynamic_update_index_in_dim(a, u, j, 0), c, kv)
+        return (h, c), None
+
+    (h, cache), _ = jax.lax.scan(period, (x, cache), jnp.arange(cfg.num_layers // p),
+                                 unroll=cfg.scan_unroll)
+    return h, cache
+
+
+def _scan_cached(cfg: ModelConfig, x, layers, body, cache):
+    """The layer walk of decode, verify and chunked prefill, each layer
+    with its cache: ``body(layer_cfg, h, (layer, layer_cache)) -> (h,
+    layer_cache)``.  A patterned config scans by period
+    (``_scan_periods``); any other keeps one ``lax.scan`` over the stacked
+    layers and cache.  Returns ``(h, cache)``."""
+    if cfg.layer_pattern is not None:
+        return _scan_periods(cfg, x, layers, body, cache)
+    return jax.lax.scan(functools.partial(body, cfg), x, (layers, cache),
+                        unroll=cfg.scan_unroll)
 
 
 def forward(
@@ -196,7 +253,12 @@ def forward(
     if positions is None:
         positions = _default_positions(cfg, b, s)
 
-    if cfg.family in ("dense", "moe", "vlm", "encoder"):
+    if cfg.family in ("dense", "moe", "vlm", "encoder") and cfg.layer_pattern is not None:
+        def period_body(lcfg, h, layer):
+            return _transformer_block(h, layer, lcfg, positions)
+
+        x, _ = _scan_periods(cfg, x, params["layers"], period_body, remat=cfg.remat)
+    elif cfg.family in ("dense", "moe", "vlm", "encoder"):
         body = lambda h, layer: _transformer_block(h, layer, cfg, positions)  # noqa: E731
         x = _scan_layers(x, params["layers"], body, cfg.remat, cfg.scan_unroll)
     elif cfg.family == "hybrid":
@@ -298,7 +360,7 @@ def decode_step(
         pos = jnp.broadcast_to(pos[..., None], (b, 1, 3))
 
     if cfg.family in ("dense", "moe", "vlm"):
-        def body(h, inp):
+        def layer_body(cfg, h, inp):
             layer, kv = inp
             hn = apply_norm(h, layer["attn_norm"], cfg.norm_type)
             a, kv_new = decode_attention(hn, layer["attn"], cfg, kv, pos)
@@ -316,9 +378,7 @@ def decode_step(
                 y = mlp_forward(hn, layer["mlp"], cfg.mlp_type, quant)
             return h + y, kv_new
 
-        x, new_cache = jax.lax.scan(
-            body, x, (params["layers"], cache), unroll=cfg.scan_unroll
-        )
+        x, new_cache = _scan_cached(cfg, x, params["layers"], layer_body, cache)
     elif cfg.family == "hybrid":
         shared = params["shared_attn"]
         every = max(cfg.attn_every, 1)
@@ -429,7 +489,7 @@ def verify_step(
     if cfg.mrope_sections is not None:
         pos = jnp.broadcast_to(pos[..., None], (b, s, 3))
 
-    def body(h, inp):
+    def layer_body(cfg, h, inp):
         layer, kv = inp
         hn = apply_norm(h, layer["attn_norm"], cfg.norm_type)
         a, kv_new = verify_attention(hn, layer["attn"], cfg, kv, pos, write_pos)
@@ -446,9 +506,7 @@ def verify_step(
             y = mlp_forward(hn, layer["mlp"], cfg.mlp_type, quant)
         return h + y, kv_new
 
-    x, new_cache = jax.lax.scan(
-        body, x, (params["layers"], cache), unroll=cfg.scan_unroll
-    )
+    x, new_cache = _scan_cached(cfg, x, params["layers"], layer_body, cache)
     x = apply_norm(x, params["final_norm"], cfg.norm_type)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     # No logit softcap, matching decode_step: tanh is monotonic, so the
@@ -491,13 +549,11 @@ def _prefill_chunk(params: dict, cfg: ModelConfig, tokens_c, cache, start: int):
     b, c = tokens_c.shape
     positions = _default_positions(cfg, b, c, offset=start)
 
-    def body(h, inp):
+    def layer_body(cfg, h, inp):
         layer, kv = inp
         return _transformer_block(h, layer, cfg, positions, kv=kv, start=start)
 
-    x, new_cache = jax.lax.scan(
-        body, x, (params["layers"], cache), unroll=cfg.scan_unroll
-    )
+    x, new_cache = _scan_cached(cfg, x, params["layers"], layer_body, cache)
     x = apply_norm(x, params["final_norm"], cfg.norm_type)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head

@@ -20,6 +20,11 @@ tokens only and the layout is explicit:
 Capacity dropping (capacity_factor, default 1.25) happens per rank over
 its local token pool, matching per-device capacity semantics of real EP
 systems.  No [T, E, C] one-hot is ever built.
+
+Serving routes dropless.  On one shard that is a grouped matmul: the T*k
+(token, expert) pairs sorted by expert run through ``jax.lax.ragged_dot``
+over the experts' group sizes (``_grouped_moe``), so no expert is padded to
+the token pool.
 """
 
 from __future__ import annotations
@@ -53,6 +58,14 @@ def moe_params(key, cfg: ModelConfig, dtype) -> dict:
     }
 
 
+def _route(xf, router, top_k):
+    """Softmax over the router's logits (float32), the top-k experts and
+    their probabilities renormalised to sum to 1: ([T, k], [T, k])."""
+    probs = jax.nn.softmax(xf.astype(jnp.float32) @ router, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, top_k)
+    return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
+
+
 def _moe_block(x, router, gate, up, down, cfg: ModelConfig,
                expert_offset, total_tokens_hint=None, dropless=False):
     """MoE over a local token block with a local expert slice.
@@ -74,10 +87,7 @@ def _moe_block(x, router, gate, up, down, cfg: ModelConfig,
     capacity = t if dropless else max(int(t * k * moe.capacity_factor / e), 1)
 
     xf = x.reshape(t, d)
-    logits = xf.astype(jnp.float32) @ router  # router is replicated
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_e = jax.lax.top_k(probs, k)  # [T, k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    top_p, top_e = _route(xf, router, k)  # [T, k]; router is replicated
 
     flat_e = top_e.reshape(-1)  # [T*k] int
     flat_p = top_p.reshape(-1)
@@ -131,6 +141,36 @@ def _moe_block(x, router, gate, up, down, cfg: ModelConfig,
     return y.reshape(b, s, d)
 
 
+def _grouped_moe(x, router, gate, up, down, cfg: ModelConfig):
+    """Dropless MoE over every expert as grouped matmuls: the T*k (token,
+    expert) pairs sorted by expert, gate, up and down as ``ragged_dot`` over
+    the sorted rows and the experts' group sizes, and each row scattered
+    back to its token weighted by its renormalised probability (summed in
+    float32)."""
+    b, s, d = x.shape
+    t, k, e = b * s, cfg.moe.top_k, cfg.moe.num_experts
+    xf = x.reshape(t, d)
+    top_p, top_e = _route(xf, router, k)
+    flat_e = top_e.reshape(-1)
+    order = jnp.argsort(flat_e, stable=True)
+    tok = order // k  # the token of each sorted pair
+    sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+    rows = xf[tok]
+    h = jax.nn.silu(jax.lax.ragged_dot(rows, gate, sizes))
+    h = h * jax.lax.ragged_dot(rows, up, sizes)
+    out = jax.lax.ragged_dot(h, down, sizes, preferred_element_type=jnp.float32)
+    w = top_p.reshape(-1)[order]
+    y = jnp.zeros((t, d), jnp.float32).at[tok].add(out * w[:, None])
+    return y.astype(x.dtype).reshape(b, s, d)
+
+
+def grouped_dropless(cfg: ModelConfig) -> bool:
+    """Whether dropless routing runs here as grouped matmuls: on one shard
+    (no 'model' axis) and without an int8 policy for the "moe" class.
+    Elsewhere it pads every expert to the token pool."""
+    return "model" not in _ambient_axis_names() and not get_quant(cfg).active("moe")
+
+
 def moe_forward(
     x: jax.Array, params: dict, cfg: ModelConfig, dropless: bool = False
 ) -> jax.Array:
@@ -142,11 +182,18 @@ def moe_forward(
     independence is what makes a token's logits identical whether it runs
     in a [B, 1] decode step or a [B, K+1] verify chunk, whatever its
     lane-mates are — the engine's token-equivalence contract for MoE.
-    Train/prefill keep the capacity-bounded EP semantics (the drop is the
-    compute-efficiency feature there).
+    Serving's prefill routes dropless too where that runs as grouped
+    matmuls (``grouped_dropless``, ``_grouped_moe``); training, and prefill
+    under EP or an int8 policy for the "moe" class, keep the
+    capacity-bounded EP semantics (the drop is the compute-efficiency
+    feature there).
     """
     moe = cfg.moe
     names = _ambient_axis_names()
+    if dropless and grouped_dropless(cfg):
+        return _grouped_moe(
+            x, params["router"], params["gate"], params["up"], params["down"], cfg
+        )
     if "model" not in names:
         # Single-shard path (unit tests / CPU smoke): all experts local.
         return _moe_block(

@@ -135,6 +135,28 @@ def test_moe_shard_map_matches_local_no_drop():
     )
 
 
+def test_moe_prefill_under_a_mesh_keeps_capacity_routing():
+    """Serving's prefill routes dropless only where the experts run
+    grouped; under EP each rank's expert buffer keeps the capacity rows
+    ([E_loc, T_loc*k*1.25/E, d]) and is never padded to its token pool."""
+    from repro.models import init_cache, prefill_step
+
+    cfg = get_smoke_config("qwen3-moe-235b-a22b")
+    b, s = 4, 16  # no other array of the module is [E_loc, T_loc, d]
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    toks = jnp.zeros((b, s), jnp.int32)
+    cache = init_cache(cfg, b, s)
+    mesh = make_debug_mesh(2, 4)
+    with jax.set_mesh(mesh):
+        text = jax.jit(lambda p, t, c: prefill_step(p, cfg, t, c, jnp.full((b,), s))).lower(
+            params, toks, cache).as_text()
+    e_loc, t_loc, d = cfg.moe.num_experts // 4, b // 2 * s, cfg.d_model
+    capacity = int(t_loc * cfg.moe.top_k * cfg.moe.capacity_factor / cfg.moe.num_experts)
+    assert capacity < t_loc
+    assert f"tensor<{e_loc}x{capacity}x{d}x" in text
+    assert f"tensor<{e_loc}x{t_loc}x{d}x" not in text
+
+
 def test_debug_mesh_lower_and_compile_cells():
     """Miniature dry-run: smoke configs x {train, decode} compile on a
     2x4 debug mesh with the same lowering code path as production."""

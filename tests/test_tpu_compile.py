@@ -156,3 +156,25 @@ def test_kernel_under_mesh_compiles_for_four_chips(topo, no_compile_cache):
         *_qkv(sh), mesh=mesh,
     )
     assert "all-gather" not in text  # each chip attends its own heads
+
+
+def test_windowed_forward_compiles_at_mellum_width(one_chip, no_compile_cache):
+    """Mellum2's sliding layers at a prefill of 8192: GQA 32:4 of 128, a
+    window of 1024 keys; the call carries its own tag."""
+    q = jax.ShapeDtypeStruct((1, 8192, 32, D), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, 8192, 4, D), jnp.bfloat16, sharding=one_chip)
+    text = _compile(lambda q, k, v: flash_attention_fwd(q, k, v, causal=True, window=1024),
+                    q, kv, kv)
+    lines = TAG.sub(lambda m: f'kernel_metadata={{"kernel":"{m[1]}"}}', text).splitlines()
+    tags = {TAG.search(x)[1] for x in lines if 'custom_call_target="tpu_custom_call"' in x}
+    assert tags == {"flash_fwd_window"}
+
+
+def test_grouped_experts_lower_to_the_native_ragged_dot(one_chip, no_compile_cache):
+    """The dropless experts' grouped matmul at Mellum2's widths (64 experts
+    of 2304 x 896) is the chip's ragged-dot kernel, not a dense fallback."""
+    rows = jax.ShapeDtypeStruct((4096, 2304), jnp.bfloat16, sharding=one_chip)
+    w = jax.ShapeDtypeStruct((64, 2304, 896), jnp.bfloat16, sharding=one_chip)
+    sizes = jax.ShapeDtypeStruct((64,), jnp.int32, sharding=one_chip)
+    text = _compile(jax.lax.ragged_dot, rows, w, sizes)
+    assert "ragged-dot" in text and " dot(" not in text
