@@ -8,7 +8,11 @@ kernel whose S/P tiles never leave VMEM:
   * grid = (batch·heads, num_q_blocks, num_k_blocks); the KV dimension is
     innermost, so the fp32 running statistics (m, l) and the output
     accumulator live in VMEM scratch across KV steps — the analogue of the
-    CMP-row registers and the accumulation SRAM;
+    CMP-row registers and the accumulation SRAM.  m and l are stored
+    lane-broadcast, ``[block_q, 128]``, the layout of a reduced row;
+  * bf16 q, k and v reach the MXU as bf16 with fp32 accumulation, and the
+    fp32 P as three bf16 slices that sum to it exactly; other dtypes run
+    both products as fp32 ``HIGHEST`` dots;
   * Br = Bc = 128 blocks match the paper's §3.5 tiling (= MXU tile);
   * softmax uses exp2 with the 1/sqrt(d) scale folded into the exp2
     argument — *exactly* Algorithm 1's operation order (rowmax on unscaled
@@ -110,6 +114,12 @@ def _exp2_inline(x: jax.Array, exp2_impl: str, num_segments: int) -> jax.Array:
     return jnp.where(x_i < -148, 0.0, out)
 
 
+def _lane_rows(x: jax.Array, width: int) -> jax.Array:
+    """A lane-broadcast ``[rows, LANES]`` statistic against a ``[rows,
+    width]`` tile: as it is where the widths match, else its first column."""
+    return x if width == LANES else x[:, :1]
+
+
 def _fwd_kernel(
     q_ref,  # [1, block_q, d]
     k_ref,  # [1, block_k, d]
@@ -157,11 +167,18 @@ def _fwd_kernel(
 
     @pl.when(live)
     def _step():
-        q = q_ref[0].astype(jnp.float32)  # [bq, d]
-        k = k_ref[0].astype(jnp.float32)  # [bk, d]
+        # bf16 operands go to the MXU as they are stored: a bf16 x bf16
+        # product is exact in fp32, so one pass forms what a fp32 HIGHEST dot
+        # (six passes) forms from their upcast.  Other dtypes upcast.
+        bf16 = all(r.dtype == jnp.bfloat16 for r in (q_ref, k_ref, v_ref))
+        if bf16:
+            q, k, precision = q_ref[0], k_ref[0], None
+        else:
+            q, k = q_ref[0].astype(jnp.float32), k_ref[0].astype(jnp.float32)
+            precision = jax.lax.Precision.HIGHEST
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
+            precision=precision,
         )  # [bq, bk] — unscaled S, as in Algorithm 1 line 6
 
         cols = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
@@ -177,33 +194,46 @@ def _fwd_kernel(
             if window is not None:
                 s = jnp.where(rows - cols < window, s, NEG_INF)
 
+        # m and l are [bq, LANES], each row's value in every lane: a row
+        # statistic then needs no relayout between its row and lane forms.
         old_m = m_scr[...]
-        local_m = jnp.max(s, axis=-1)
-        new_m = jnp.maximum(local_m, old_m)                      # line 8
-        b = _exp2_inline(c * (old_m - new_m), exp2_impl, num_segments)  # line 10
-        p = _exp2_inline(c * (s - new_m[:, None]), exp2_impl, num_segments)  # line 12
-        l_scr[...] = l_scr[...] * b + jnp.sum(p, axis=-1)        # lines 13-14
-        v = v_ref[0].astype(jnp.float32)
-        # Both products run at contract precision fp32: P stays fp32, as in
-        # the decode einsum that must agree with this kernel.
-        local_o = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
-            precision=jax.lax.Precision.HIGHEST,
+        new_m = jnp.maximum(jnp.max(s, axis=-1, keepdims=True), old_m)      # line 8
+        b = _exp2_inline(c * (old_m - new_m), exp2_impl, num_segments)        # line 10
+        p = _exp2_inline(c * (s - _lane_rows(new_m, block_k)), exp2_impl,
+                         num_segments)                                        # line 12
+        l_scr[...] = l_scr[...] * b + jnp.sum(p, axis=-1, keepdims=True)    # lines 13-14
+        # P stays fp32, as in the decode einsum that must agree with this
+        # kernel.  Against bf16 V it enters as three bf16 slices that sum to
+        # it exactly, so three passes form every product that a fp32 HIGHEST
+        # dot on upcast V forms.
+        pv = functools.partial(
+            jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
         )
-        acc_scr[...] = acc_scr[...] * b[:, None] + local_o       # line 16
+        if bf16:
+            v = v_ref[0]
+            p1 = p.astype(jnp.bfloat16)
+            rest = p - p1.astype(jnp.float32)
+            p2 = rest.astype(jnp.bfloat16)
+            p3 = (rest - p2.astype(jnp.float32)).astype(jnp.bfloat16)
+            local_o = (pv(p3, v) + pv(p2, v)) + pv(p1, v)
+        else:
+            local_o = pv(p, v_ref[0].astype(jnp.float32), precision=jax.lax.Precision.HIGHEST)
+        acc_scr[...] = acc_scr[...] * _lane_rows(b, acc_scr.shape[1]) + local_o  # line 16
         m_scr[...] = new_m
 
     @pl.when(j == num_k_blocks - 1)
     def _finalize():  # line 21: O_i = diag(l)^-1 O
         l = l_scr[...]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, :] = (acc_scr[...] / safe_l[:, None]).astype(o_ref.dtype)
+        o = acc_scr[...] / _lane_rows(safe_l, acc_scr.shape[1])
+        o_ref[0, :, :] = o.astype(o_ref.dtype)
         if with_lse:
             # Base-2 LSE with the scale folded in: P = exp2(c*S - LSE) is
             # the *normalized* probability the backward recomputes.  Stored
-            # lane-broadcast: a [block_q] row block is not (8, 128)-tiled.
-            lse = c * m_scr[...] + jnp.log2(safe_l)
-            lse_ref[0] = jnp.broadcast_to(lse[:, None], lse_ref.shape[1:])
+            # lane-broadcast, as m and l are: a [block_q] row block is not
+            # (8, 128)-tiled.
+            lse_ref[0] = c * m_scr[...] + jnp.log2(safe_l)
 
 
 def kv_block_index(causal, block_q, block_k, q_offset):
@@ -343,8 +373,8 @@ def flash_attention_fwd(
             else jax.ShapeDtypeStruct((batch * h, num_q * block_q, d), q.dtype)
         ),
         scratch_shapes=[
-            pltpu.VMEM((block_q,), jnp.float32),
-            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
+            pltpu.VMEM((block_q, LANES), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
         interpret=interpret,

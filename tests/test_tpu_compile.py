@@ -3,7 +3,8 @@
 Interpret mode (tests/test_kernels.py) checks numerics but not what the
 chip's compiler accepts: block tiling, VMEM use, partitioning.  These tests
 compile for a described v5e:2x2 topology, which needs the TPU compiler but
-no chip, at q/k/v ``[1, 2048, 16, 128]`` bf16, causal.  Nothing runs.
+no chip, at q/k/v ``[1, 2048, 16, 128]`` bf16 (and fp32 for one forward),
+causal.  Nothing runs.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
@@ -77,6 +78,10 @@ CASES = {
     "fwd_lse": lambda sh: (
         lambda q, k, v: flash_attention_fwd(q, k, v, causal=True, return_lse=True),
         _qkv(sh)),
+    # fp32 inputs: both products stay fp32 HIGHEST dots.
+    "fwd_f32": lambda sh: (
+        lambda q, k, v: flash_attention_fwd(q, k, v, causal=True),
+        [x.update(dtype=jnp.float32) for x in _qkv(sh)]),
     "fwd_pwl": lambda sh: (
         lambda q, k, v: flash_attention_fwd(q, k, v, causal=True, exp2_impl="pwl"),
         _qkv(sh)),
