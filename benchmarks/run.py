@@ -6,7 +6,6 @@ envelope, Table 3 area overhead, §3.5 cycle counts).
 
 Also emits machine-readable ``BENCH_*.json`` files into the working
 directory — ``BENCH_serve.json`` (continuous-batching decode tokens/s),
-``BENCH_flash.json`` (flash attention fwd/bwd FLOPs/s vs references),
 ``BENCH_quant.json`` (int8 decode throughput, KV-cache footprint and
 greedy fidelity), ``BENCH_spec.json`` (speculative decoding acceptance
 rate and target-step reduction), ``BENCH_train.json`` (train-step
@@ -34,7 +33,6 @@ def main() -> None:
         fig1_active_time,
         fig11_utilization,
         fig12_pwl_error,
-        flash_bench,
         quant_bench,
         section35_cycles,
         serve_bench,
@@ -53,7 +51,6 @@ def main() -> None:
         ("table3", table3_area),
         ("sec35", section35_cycles),
         ("serve", serve_bench),
-        ("flash", flash_bench),
         ("quant", quant_bench),
         ("spec", spec_bench),
         ("train", train_bench),

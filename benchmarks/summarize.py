@@ -6,7 +6,7 @@ Run after ``python -m benchmarks.run``:
 
 Prints the table to stdout and, when ``GITHUB_STEP_SUMMARY`` is set
 (inside a GitHub Actions step), appends it there too — so every CI run
-shows serve/flash/quant/spec/train throughput on the run page without
+shows serve/quant/spec/train throughput on the run page without
 downloading the artifact.
 """
 

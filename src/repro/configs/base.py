@@ -85,9 +85,11 @@ class ModelConfig:
     rope_yarn: Optional[YarnConfig] = None
     # Execution knobs
     parallelism: str = "tp"  # tp (Megatron TP+DP+SP) | dp_only (pure DP+ZeRO)
-    # None: by platform (models.attention._impl_attention) — the compiled
-    # Pallas kernel on TPU, the systolic jnp scan elsewhere.  Set to
-    # "systolic" | "pallas" | "naive" to force one (e.g. an f32 reference).
+    # Full-sequence attention (training, prefill), read by
+    # models.attention._impl_attention.  None: by platform — the compiled
+    # Pallas kernel on TPU, the systolic jnp scan elsewhere.  "pallas" or
+    # "systolic" forces one (e.g. the kernel compiled for a described chip,
+    # or an f32 reference); any other value raises.
     attention_impl: Optional[str] = None
     exp2_impl: str = "exact"  # exact | pwl (paper-faithful numerics)
     attn_block_q: int = 128
@@ -107,7 +109,6 @@ class ModelConfig:
     # Frontend stubs ([audio]/[vlm]): the model consumes precomputed
     # frame/patch embeddings instead of token ids.
     embedding_inputs: bool = False
-    logit_softcap: float = 0.0
 
     @property
     def num_scan_steps(self) -> int:

@@ -11,11 +11,12 @@ FlashAttention-2/3 forward with
     FSA accumulator both accumulate in fp32).
 
 It is written with `jax.lax.scan` over key/value tiles so it lowers to clean
-HLO on any backend — this is also the implementation used by the multi-pod
-dry-run cells (Pallas does not lower on the CPU host platform; see
-DESIGN.md §6).  The Pallas TPU kernel in ``repro.kernels.flash_attention``
-implements the same schedule with explicit VMEM BlockSpecs and is validated
-against this function and the naive oracle.
+HLO on any backend: it is the model's attention off TPU (tests, smoke runs,
+the multi-pod dry-run cells; a Pallas TPU kernel does not lower for the CPU
+host platform), and JAX differentiates it directly.  The Pallas TPU kernel
+in ``repro.kernels.flash_attention`` implements the same schedule with
+explicit VMEM BlockSpecs and is validated against this function and
+``naive_attention``, the materialized-softmax oracle of the tests.
 """
 
 from __future__ import annotations

@@ -1,31 +1,37 @@
-"""GQA attention layer: params, forward (train/prefill), decode with KV cache.
+"""GQA attention layer: params, and one attention path per phase.
 
-The paper's technique enters here.  Full-sequence attention (training and
-prefill) runs
+The paper's technique enters here.  Each phase has one function:
 
-  * ``pallas``   — the fused Pallas TPU kernel (``repro.kernels``), compiled;
-    the default on TPU;
+  * training and ``forward`` — ``attention_forward``: full-sequence
+    attention;
+  * prefill — ``prefill_attention``: one chunk's K/V written into the
+    cache at a static ``start``, its queries attending ``[0, start+C)``;
+  * decode and speculative verify — ``cached_attention``: S new tokens per
+    slot (decode is S = 1 at ``write_pos = cache.lengths``) scattered into
+    the live cache and attended with a grouped einsum.
+
+The first two attend through ``_impl_attention``, which runs
+
+  * ``pallas``   — the fused Pallas TPU kernel (``repro.kernels``), the
+    default on TPU;
   * ``systolic`` — the Algorithm-1-faithful tiled jnp implementation
-    (``repro.core.attention``), lowers on all backends; the default
-    elsewhere (tests, the CPU dry-run) and the f32 reference;
-  * ``naive``    — materialized softmax (oracle).
+    (``repro.core.attention``), which lowers on every backend: the default
+    elsewhere, and the f32 reference.
 
-``cfg.attention_impl`` forces one; None picks from the platform.
-
-Per the paper §8.3, decode (seq_q == 1, memory-bound) never uses the FSA
-path: a 1-token query would waste a 128x128 tile.  ``decode_attention``
-is a plain einsum over the KV cache.
+``cfg.attention_impl`` forces one; None picks from the platform.  Per the
+paper §8.3, cached attention (a few queries, memory-bound) never uses the
+FSA path.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.core.attention import naive_attention, systolic_attention
+from repro.core.attention import systolic_attention
 from repro.dist.collectives import map_heads
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.quant import dequantize_kv, get_quant, quantize_kv
@@ -109,8 +115,8 @@ def _window(cfg: ModelConfig):
 def _impl_attention(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
     """Dispatch full-sequence attention to the configured implementation.
 
-    Shared by training/prefill (``attention_forward``) and the chunked
-    flash prefill (``prefill_attention``) so both paths produce identical
+    Shared by training (``attention_forward``) and the chunked flash
+    prefill (``prefill_attention``) so both paths produce identical
     numerics for the same (q, k, v) — the token-equivalence contract of
     the serving engine depends on this.
     """
@@ -118,18 +124,17 @@ def _impl_attention(q, k, v, cfg: ModelConfig, q_offset: int = 0) -> jax.Array:
     window = _window(cfg)
     if impl is None:
         impl = "pallas" if jax.default_backend() == "tpu" else "systolic"
-    if impl == "naive":
-        return naive_attention(q, k, v, causal=cfg.causal, q_offset=q_offset, window=window)
     if impl == "pallas":
         def kernel(q, k, v):
             return flash_attention(
-                q, k, v, cfg.causal, None, q_offset,
-                cfg.attn_block_q, cfg.attn_block_k, cfg.exp2_impl, 8, "pallas",
-                False, window,
+                q, k, v, causal=cfg.causal, q_offset=q_offset,
+                block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+                exp2_impl=cfg.exp2_impl, window=window,
             )
 
         return map_heads(kernel, q, k, v)
-    # systolic (paper-faithful jnp; dry-run / CPU path)
+    if impl != "systolic":
+        raise ValueError(f"attention_impl {impl!r}: expected None, 'pallas' or 'systolic'")
     return systolic_attention(
         q, k, v,
         causal=cfg.causal,
@@ -148,12 +153,44 @@ def attention_forward(
     cfg: ModelConfig,
     positions: jax.Array,  # [B, S] (or [B, S, 3] for M-RoPE)
 ) -> jax.Array:
-    """Full-sequence attention (training / prefill)."""
+    """Full-sequence attention (training, and ``forward``)."""
     b, s, _ = x.shape
     q, k, v = _project_qkv(x, params, cfg, positions)
     o = _impl_attention(q, k, v, cfg)
     o = o.reshape(b, s, cfg.num_heads * cfg.resolved_head_dim)
     return get_quant(cfg).dot(o, params["wo"], "attention")
+
+
+def _store_kv(cfg: ModelConfig, cache, k_new, v_new, write, read_len, dtype):
+    """Store new K/V rows in ``cache`` -> (new cache, K, V to attend over).
+
+    ``write(buffer, rows)`` puts one buffer's new rows in place.  An int8
+    cache quantizes on write, one scale per token/head vector, so a row is
+    byte-identical whichever phase wrote it, and is read back dequantized
+    to ``dtype``; a float cache casts to its own dtype.  The K/V read back
+    cover rows ``[0, read_len)`` (None: all).  ``lengths`` is left as it is.
+    """
+    def read(a):
+        return a if read_len is None else a[:, :read_len]
+
+    if get_quant(cfg).quantized_kv:
+        kq, ks = quantize_kv(k_new)
+        vq, vs = quantize_kv(v_new)
+        new = QuantKVCache(
+            k=write(cache.k, kq),
+            v=write(cache.v, vq),
+            k_scale=write(cache.k_scale, ks),
+            v_scale=write(cache.v_scale, vs),
+            lengths=cache.lengths,
+        )
+        return (new, dequantize_kv(read(new.k), read(new.k_scale), dtype),
+                dequantize_kv(read(new.v), read(new.v_scale), dtype))
+    new = KVCache(
+        k=write(cache.k, k_new.astype(cache.k.dtype)),
+        v=write(cache.v, v_new.astype(cache.v.dtype)),
+        lengths=cache.lengths,
+    )
+    return new, read(new.k), read(new.v)
 
 
 def prefill_attention(
@@ -175,35 +212,12 @@ def prefill_attention(
     """
     b, c, _ = x.shape
     q, k_new, v_new = _project_qkv(x, params, cfg, positions)
-    if get_quant(cfg).quantized_kv:
-        # Quantize on insert: each token/head vector gets its own scale, so
-        # the chunk write is byte-identical to what a per-token decode
-        # scatter-write would have produced.
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        dus = jax.lax.dynamic_update_slice_in_dim
-        new_cache = QuantKVCache(
-            k=dus(cache.k, kq, start, axis=1),
-            v=dus(cache.v, vq, start, axis=1),
-            k_scale=dus(cache.k_scale, ks, start, axis=1),
-            v_scale=dus(cache.v_scale, vs, start, axis=1),
-            lengths=cache.lengths,
-        )
-        span = slice(None, start + c)
-        k = dequantize_kv(new_cache.k[:, span], new_cache.k_scale[:, span], x.dtype)
-        v = dequantize_kv(new_cache.v[:, span], new_cache.v_scale[:, span], x.dtype)
-        o = _impl_attention(q, k, v, cfg, q_offset=start)
-    else:
-        k = jax.lax.dynamic_update_slice_in_dim(
-            cache.k, k_new.astype(cache.k.dtype), start, axis=1
-        )
-        v = jax.lax.dynamic_update_slice_in_dim(
-            cache.v, v_new.astype(cache.v.dtype), start, axis=1
-        )
-        new_cache = KVCache(k=k, v=v, lengths=cache.lengths)
-        o = _impl_attention(
-            q, k[:, : start + c], v[:, : start + c], cfg, q_offset=start
-        )
+
+    def write(buf, rows):
+        return jax.lax.dynamic_update_slice_in_dim(buf, rows, start, axis=1)
+
+    new_cache, k, v = _store_kv(cfg, cache, k_new, v_new, write, start + c, x.dtype)
+    o = _impl_attention(q, k, v, cfg, q_offset=start)
     o = o.reshape(b, c, cfg.num_heads * cfg.resolved_head_dim)
     return get_quant(cfg).dot(o, params["wo"], "attention"), new_cache
 
@@ -236,29 +250,27 @@ def _pv(p: jax.Array, v: jax.Array) -> jax.Array:
     )
 
 
-def verify_attention(
-    x: jax.Array,  # [B, S, d_model] — S teacher-forced tokens per slot
+def cached_attention(
+    x: jax.Array,  # [B, S, d_model] — S new tokens per slot
     params: dict,
     cfg: ModelConfig,
     cache: KVCache,
     positions: jax.Array,  # [B, S] (or [B, S, 3]) absolute positions
     write_pos: jax.Array,  # [B] int32: first write row per slot
 ) -> tuple[jax.Array, KVCache]:
-    """Batched speculative-verify attention: score S tokens per slot in one
-    pass against the *live* decode cache.
+    """S new tokens per slot against the live cache: decode (S = 1,
+    ``write_pos = cache.lengths``) and speculative verify (S = K+1).
 
-    The spec-decoding core (repro.spec): S = K+1 proposed tokens enter as
-    one wide teacher-forced chunk — the consecutive-large-matmul shape the
-    paper's FSA scheduling thrives on, instead of K memory-bound 1-token
-    decode steps.  Slot i's rows are scattered at ``write_pos[i] + j`` (its
-    own decode depth, unlike ``prefill_attention``'s batch-static ``start``)
-    and query j attends keys at absolute positions ``<= write_pos[i] + j``.
-    Row j therefore sees exactly the cache a sequential ``decode_attention``
-    step would have seen, so greedy acceptance is lossless.
+    Paper §8.3: never the FSA path — a 1-token query would waste a 128x128
+    tile.  Slot i's rows are scattered at ``write_pos[i] + j`` (its own
+    depth, so requests at arbitrary depths share one batched step; rows past
+    capacity drop their write) and query j attends keys at absolute
+    positions ``<= write_pos[i] + j`` (and, in a sliding layer, within its
+    window).  Verify's row j therefore sees exactly the cache a sequential
+    decode step would have seen, so greedy acceptance is lossless.
 
-    ``cache.lengths`` is left untouched: acceptance (and the rollback that
-    truncates rejected suffixes) is decided by the caller once the verify
-    logits are known — see ``repro.spec.verify``.
+    ``cache.lengths`` is left untouched: decode advances it, and verify's
+    caller decides how many rows survive (``repro.spec.verify``).
     """
     b, s_new, _ = x.shape
     hd = cfg.resolved_head_dim
@@ -266,29 +278,16 @@ def verify_attention(
 
     slot = jnp.arange(b)[:, None]  # [B, 1]
     rows = write_pos[:, None] + jnp.arange(s_new)[None, :]  # [B, S]
-    if get_quant(cfg).quantized_kv:
-        # Same per-token/head quantize-on-write as the decode scatter, so
-        # accepted rows are byte-identical to sequential decode's writes.
-        kq, ks = quantize_kv(k_new)
-        vq, vs = quantize_kv(v_new)
-        new_cache = QuantKVCache(
-            k=cache.k.at[slot, rows].set(kq, mode="drop"),
-            v=cache.v.at[slot, rows].set(vq, mode="drop"),
-            k_scale=cache.k_scale.at[slot, rows].set(ks, mode="drop"),
-            v_scale=cache.v_scale.at[slot, rows].set(vs, mode="drop"),
-            lengths=cache.lengths,
-        )
-        k = dequantize_kv(new_cache.k, new_cache.k_scale)
-        v = dequantize_kv(new_cache.v, new_cache.v_scale)
-    else:
-        k = cache.k.at[slot, rows].set(k_new.astype(cache.k.dtype), mode="drop")
-        v = cache.v.at[slot, rows].set(v_new.astype(cache.v.dtype), mode="drop")
-        new_cache = KVCache(k=k, v=v, lengths=cache.lengths)
 
-    # Same grouped-einsum formulation (and fp32 softmax) as
-    # ``decode_attention``, widened from 1 query to S — the mask reduces to
-    # decode's ``key <= lengths`` row by row, which is what keeps verify
-    # argmax-identical to the sequential decode it replaces.
+    def write(buf, new):
+        return buf.at[slot, rows].set(new, mode="drop")
+
+    new_cache, k, v = _store_kv(cfg, cache, k_new, v_new, write, None, jnp.float32)
+
+    # GQA via grouped einsum — materializing jnp.repeat(k, rep) would blow
+    # the cache up rep x (16x for qwen3) and force GSPMD to reshard it every
+    # step (measured: the dominant decode collective cost).  Softmax in
+    # fp32; the cache stays dense and the mask hides rows past each query.
     rep = cfg.num_heads // cfg.num_kv_heads
     qg = q.reshape(b, s_new, cfg.num_kv_heads, rep, hd).astype(jnp.float32)
     scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
@@ -301,66 +300,4 @@ def verify_attention(
     p = jax.nn.softmax(s, axis=-1)
     o = _pv(p, v).astype(x.dtype)
     o = o.reshape(b, s_new, cfg.num_heads * hd)
-    return get_quant(cfg).dot(o, params["wo"], "attention"), new_cache
-
-
-def decode_attention(
-    x: jax.Array,  # [B, 1, d_model]
-    params: dict,
-    cfg: ModelConfig,
-    cache: KVCache,
-    positions: jax.Array,  # [B, 1] (or [B, 1, 3])
-) -> tuple[jax.Array, KVCache]:
-    """Single-token decode against the KV cache (paper §8.3: never FSA).
-
-    Per-slot positions: slot i's new K/V is scattered at ``lengths[i]``, so
-    requests at arbitrary decode depths share one batched step (continuous
-    batching).  Slots whose length has reached capacity drop their write.
-    """
-    b = x.shape[0]
-    hd = cfg.resolved_head_dim
-    q, k_new, v_new = _project_qkv(x, params, cfg, positions)
-
-    slot = jnp.arange(b)
-    if get_quant(cfg).quantized_kv:
-        # Quantize on the decode scatter-write; attention below runs over
-        # the dequantized cache (identical values to the prefill path).
-        kq, ks = quantize_kv(k_new[:, 0])
-        vq, vs = quantize_kv(v_new[:, 0])
-        new_cache = QuantKVCache(
-            k=cache.k.at[slot, cache.lengths].set(kq, mode="drop"),
-            v=cache.v.at[slot, cache.lengths].set(vq, mode="drop"),
-            k_scale=cache.k_scale.at[slot, cache.lengths].set(ks, mode="drop"),
-            v_scale=cache.v_scale.at[slot, cache.lengths].set(vs, mode="drop"),
-            lengths=cache.lengths + 1,
-        )
-        k = dequantize_kv(new_cache.k, new_cache.k_scale)
-        v = dequantize_kv(new_cache.v, new_cache.v_scale)
-    else:
-        k = cache.k.at[slot, cache.lengths].set(
-            k_new[:, 0].astype(cache.k.dtype), mode="drop"
-        )
-        v = cache.v.at[slot, cache.lengths].set(
-            v_new[:, 0].astype(cache.v.dtype), mode="drop"
-        )
-        new_cache = KVCache(k=k, v=v, lengths=cache.lengths + 1)
-
-    # GQA via grouped einsum — materializing jnp.repeat(k, rep) would blow
-    # the cache up rep x (16x for qwen3) and force GSPMD to reshard it every
-    # step (measured: the dominant decode collective cost).
-    rep = cfg.num_heads // cfg.num_kv_heads
-    qg = q.reshape(b, 1, cfg.num_kv_heads, rep, hd).astype(jnp.float32)
-    scale = 1.0 / jnp.sqrt(jnp.asarray(hd, jnp.float32))
-    s = jnp.einsum("bqhrd,bkhd->bhrqk", qg, k.astype(jnp.float32)) * scale
-    # Mask positions beyond each slot's (updated) cache length, and in a
-    # sliding layer those older than its window; the cache stays dense.
-    keys = jnp.arange(k.shape[1])[None, None, None, None, :]
-    pos = cache.lengths[:, None, None, None, None]
-    valid = keys <= pos
-    if _window(cfg) is not None:
-        valid &= keys > pos - cfg.sliding_window
-    s = jnp.where(valid, s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    o = _pv(p, v).astype(x.dtype)
-    o = o.reshape(b, 1, cfg.num_heads * hd)
     return get_quant(cfg).dot(o, params["wo"], "attention"), new_cache

@@ -15,7 +15,7 @@ axis and consumed by ``jax.lax.scan`` so the per-layer HLO is compiled once
 from __future__ import annotations
 
 import functools
-from typing import Any, NamedTuple, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -28,10 +28,9 @@ from .attention import (
     QuantKVCache,
     attention_forward,
     attention_params,
-    decode_attention,
+    cached_attention,
     init_kv_cache,
     prefill_attention,
-    verify_attention,
 )
 from .layers import apply_norm, embed_init, mlp_forward, mlp_params, norm_params
 from .moe import grouped_dropless, moe_forward, moe_params
@@ -142,35 +141,81 @@ def _sp(x, cfg: ModelConfig):
     return constrain(x, ("pod", "data"), "model", None)
 
 
-def _transformer_block(x, layer, cfg: ModelConfig, positions, kv=None, start=0):
-    """One transformer block.  With ``kv`` (a per-layer KVCache) the
-    attention sub-block runs the chunked-prefill path — K/V written into
-    the cache at [start, start+S) — and the updated cache is returned
-    alongside the activations; without it, plain full-sequence attention.
-    Both paths share the same MLP/norm code and attention dispatch, so
-    prefill-into-cache and training forward are numerically identical."""
-    x = _sp(x, cfg)
+class _Phase(NamedTuple):
+    """What one phase gives ``_transformer_block``: its attention sub-block
+    ``attend(h, attn_params, cfg, kv) -> (a, kv)``, its MoE routing
+    (``dropless``), and whether the residual is sharded by sequence
+    (``_sp``)."""
+
+    attend: Callable
+    dropless: bool
+    seq_parallel: bool
+
+
+def _full_phase(positions) -> _Phase:
+    """Training and ``forward``: full-sequence attention, no cache, and
+    capacity routing."""
+    def attend(h, p, cfg, kv):
+        return attention_forward(h, p, cfg, positions), kv
+
+    return _Phase(attend, dropless=False, seq_parallel=True)
+
+
+def _prefill_phase(cfg: ModelConfig, positions, start: int) -> _Phase:
+    """Prefill: the chunk's K/V written into the cache at ``start``.
+    Prefill into a cache is serving: it routes dropless, as decode does,
+    where the experts run grouped.  Under EP or an int8 "moe" policy
+    dropless pads every expert to the token pool, so prefill keeps
+    capacity routing there."""
+    def attend(h, p, lcfg, kv):
+        return prefill_attention(h, p, lcfg, kv, positions, start)
+
+    return _Phase(attend, dropless=grouped_dropless(cfg), seq_parallel=True)
+
+
+def _cached_phase(positions, write_pos=None) -> _Phase:
+    """Decode and verify: S new rows per slot at ``write_pos`` (None: each
+    layer's own ``cache.lengths``, where decode writes).  Dropless: a
+    token's routing must not depend on its lane-mates (dead slots, other
+    slots' depths), so verify row j equals the decode step it replaces.
+    The residual of a few rows per slot stays whole."""
+    def attend(h, p, cfg, kv):
+        at = kv.lengths if write_pos is None else write_pos
+        return cached_attention(h, p, cfg, kv, positions, at)
+
+    return _Phase(attend, dropless=True, seq_parallel=False)
+
+
+def _transformer_block(x, layer, cfg: ModelConfig, phase: _Phase, kv=None):
+    """One transformer block -> ``(x, kv)``, for every phase: ``phase``
+    supplies the attention over ``kv`` (a per-layer cache, or None), the MoE
+    routing and the residual sharding.  The norms, residuals and MLP are
+    shared, so prefill-into-cache and training forward are numerically
+    identical."""
+    def shard(x):
+        return _sp(x, cfg) if phase.seq_parallel else x
+
+    x = shard(x)
     h = apply_norm(x, layer["attn_norm"], cfg.norm_type)
-    if kv is None:
-        a = attention_forward(h, layer["attn"], cfg, positions)
-    else:
-        a, kv = prefill_attention(h, layer["attn"], cfg, kv, positions, start)
+    a, kv = phase.attend(h, layer["attn"], cfg, kv)
     x = x + a
-    x = _sp(x, cfg)
+    x = shard(x)
     h = apply_norm(x, layer["mlp_norm"], cfg.norm_type)
     quant = get_quant(cfg)
     if cfg.moe is not None:
-        # Prefill into a cache is serving: it routes dropless, as decode
-        # does, where the experts run grouped.  Under EP or an int8 "moe"
-        # policy dropless pads every expert to the token pool, so prefill
-        # keeps capacity routing there.
-        y = moe_forward(h, layer["moe"], cfg, dropless=kv is not None and grouped_dropless(cfg))
+        y = moe_forward(h, layer["moe"], cfg, dropless=phase.dropless)
         if cfg.moe.dense_residual:
             y = y + mlp_forward(h, layer["dense_mlp"], cfg.mlp_type, quant)
     else:
         y = mlp_forward(h, layer["mlp"], cfg.mlp_type, quant)
-    out = _sp(x + y, cfg)
-    return out if kv is None else (out, kv)
+    return shard(x + y), kv
+
+
+def _head(params: dict, cfg: ModelConfig, x) -> jax.Array:
+    """Final norm and LM head -> logits."""
+    x = apply_norm(x, params["final_norm"], cfg.norm_type)
+    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+    return x @ head
 
 
 def _scan_layers(x, stacked, body, remat: bool, unroll: int = 1):
@@ -224,12 +269,15 @@ def _scan_periods(cfg: ModelConfig, x, layers, body, cache=None, remat: bool = F
     return h, cache
 
 
-def _scan_cached(cfg: ModelConfig, x, layers, body, cache):
-    """The layer walk of decode, verify and chunked prefill, each layer
-    with its cache: ``body(layer_cfg, h, (layer, layer_cache)) -> (h,
-    layer_cache)``.  A patterned config scans by period
-    (``_scan_periods``); any other keeps one ``lax.scan`` over the stacked
-    layers and cache.  Returns ``(h, cache)``."""
+def _scan_cached(cfg: ModelConfig, x, layers, cache, phase: _Phase):
+    """The layer walk of decode, verify and chunked prefill: every layer a
+    ``_transformer_block`` with its own cache.  A patterned config scans by
+    period (``_scan_periods``); any other keeps one ``lax.scan`` over the
+    stacked layers and cache.  Returns ``(h, cache)``."""
+    def body(lcfg, h, inp):
+        layer, kv = inp
+        return _transformer_block(h, layer, lcfg, phase, kv)
+
     if cfg.layer_pattern is not None:
         return _scan_periods(cfg, x, layers, body, cache)
     return jax.lax.scan(functools.partial(body, cfg), x, (layers, cache),
@@ -253,13 +301,16 @@ def forward(
     if positions is None:
         positions = _default_positions(cfg, b, s)
 
+    phase = _full_phase(positions)
     if cfg.family in ("dense", "moe", "vlm", "encoder") and cfg.layer_pattern is not None:
         def period_body(lcfg, h, layer):
-            return _transformer_block(h, layer, lcfg, positions)
+            return _transformer_block(h, layer, lcfg, phase)[0]
 
         x, _ = _scan_periods(cfg, x, params["layers"], period_body, remat=cfg.remat)
     elif cfg.family in ("dense", "moe", "vlm", "encoder"):
-        body = lambda h, layer: _transformer_block(h, layer, cfg, positions)  # noqa: E731
+        def body(h, layer):
+            return _transformer_block(h, layer, cfg, phase)[0]
+
         x = _scan_layers(x, params["layers"], body, cfg.remat, cfg.scan_unroll)
     elif cfg.family == "hybrid":
         shared = params["shared_attn"]
@@ -270,7 +321,7 @@ def forward(
             layer, idx = inp
 
             def with_attn(h):
-                return _transformer_block(h, shared, cfg, positions)
+                return _transformer_block(h, shared, cfg, phase)[0]
 
             h = jax.lax.cond(idx % every == 0, with_attn, lambda h: h, h)
             hn = apply_norm(h, layer["norm"], cfg.norm_type)
@@ -296,12 +347,7 @@ def forward(
     else:
         raise ValueError(cfg.family)
 
-    x = apply_norm(x, params["final_norm"], cfg.norm_type)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    if cfg.logit_softcap:
-        logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-    return logits
+    return _head(params, cfg, x)
 
 
 # ---------------------------------------------------------------------------
@@ -359,26 +405,12 @@ def decode_step(
     if cfg.mrope_sections is not None:
         pos = jnp.broadcast_to(pos[..., None], (b, 1, 3))
 
+    # Every attention layer's cache takes one row per slot at its length;
+    # the lengths advance here, once per step.
+    phase = _cached_phase(pos)
     if cfg.family in ("dense", "moe", "vlm"):
-        def layer_body(cfg, h, inp):
-            layer, kv = inp
-            hn = apply_norm(h, layer["attn_norm"], cfg.norm_type)
-            a, kv_new = decode_attention(hn, layer["attn"], cfg, kv, pos)
-            h = h + a
-            hn = apply_norm(h, layer["mlp_norm"], cfg.norm_type)
-            quant = get_quant(cfg)
-            if cfg.moe is not None:
-                # dropless: a decode token's routing must not depend on its
-                # lane-mates (dead slots, other slots' depths) — capacity
-                # competition across lanes would break per-slot determinism.
-                y = moe_forward(hn, layer["moe"], cfg, dropless=True)
-                if cfg.moe.dense_residual:
-                    y = y + mlp_forward(hn, layer["dense_mlp"], cfg.mlp_type, quant)
-            else:
-                y = mlp_forward(hn, layer["mlp"], cfg.mlp_type, quant)
-            return h + y, kv_new
-
-        x, new_cache = _scan_cached(cfg, x, params["layers"], layer_body, cache)
+        x, new_cache = _scan_cached(cfg, x, params["layers"], cache, phase)
+        new_cache = new_cache._replace(lengths=new_cache.lengths + 1)
     elif cfg.family == "hybrid":
         shared = params["shared_attn"]
         every = max(cfg.attn_every, 1)
@@ -390,12 +422,7 @@ def decode_step(
 
             def with_attn(args):
                 h, kv = args
-                hn = apply_norm(h, shared["attn_norm"], cfg.norm_type)
-                a, kv_new = decode_attention(hn, shared["attn"], cfg, kv, pos)
-                h = h + a
-                hn = apply_norm(h, shared["mlp_norm"], cfg.norm_type)
-                h = h + mlp_forward(hn, shared["mlp"], cfg.mlp_type, get_quant(cfg))
-                return h, kv_new
+                return _transformer_block(h, shared, cfg, phase, kv)
 
             attn_slot = idx // every
             kv = jax.tree.map(lambda c: c[attn_slot], cache["attn"])
@@ -423,6 +450,7 @@ def decode_step(
             cache["attn"],
             kvs,
         )
+        new_attn = new_attn._replace(lengths=new_attn.lengths + 1)
         new_cache = {"attn": new_attn, "mamba": mcs}
     elif cfg.family == "ssm":
         def ssm_body(h, inp):
@@ -443,11 +471,7 @@ def decode_step(
         new_cache = {"mlstm": ms_all, "slstm": ss_all}
     else:
         raise ValueError(cfg.family)
-
-    x = apply_norm(x, params["final_norm"], cfg.norm_type)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    return logits, new_cache
+    return _head(params, cfg, x), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -485,34 +509,11 @@ def verify_step(
     b, s = tokens.shape
     positions = jnp.asarray(positions, jnp.int32)
     pos = positions[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :]  # [B, S]
-    write_pos = positions
     if cfg.mrope_sections is not None:
         pos = jnp.broadcast_to(pos[..., None], (b, s, 3))
-
-    def layer_body(cfg, h, inp):
-        layer, kv = inp
-        hn = apply_norm(h, layer["attn_norm"], cfg.norm_type)
-        a, kv_new = verify_attention(hn, layer["attn"], cfg, kv, pos, write_pos)
-        h = h + a
-        hn = apply_norm(h, layer["mlp_norm"], cfg.norm_type)
-        quant = get_quant(cfg)
-        if cfg.moe is not None:
-            # Same dropless routing as decode_step: verify row j must equal
-            # the decode step it replaces regardless of lane-mates.
-            y = moe_forward(hn, layer["moe"], cfg, dropless=True)
-            if cfg.moe.dense_residual:
-                y = y + mlp_forward(hn, layer["dense_mlp"], cfg.mlp_type, quant)
-        else:
-            y = mlp_forward(hn, layer["mlp"], cfg.mlp_type, quant)
-        return h + y, kv_new
-
-    x, new_cache = _scan_cached(cfg, x, params["layers"], layer_body, cache)
-    x = apply_norm(x, params["final_norm"], cfg.norm_type)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    # No logit softcap, matching decode_step: tanh is monotonic, so the
-    # greedy argmax the engine compares/emits is unchanged either way.
-    logits = x @ head
-    return logits, new_cache
+    x, new_cache = _scan_cached(cfg, x, params["layers"], cache,
+                                _cached_phase(pos, positions))
+    return _head(params, cfg, x), new_cache
 
 
 def rollback_cache(cache: Any, new_lengths: jax.Array) -> Any:
@@ -549,17 +550,9 @@ def _prefill_chunk(params: dict, cfg: ModelConfig, tokens_c, cache, start: int):
     b, c = tokens_c.shape
     positions = _default_positions(cfg, b, c, offset=start)
 
-    def layer_body(cfg, h, inp):
-        layer, kv = inp
-        return _transformer_block(h, layer, cfg, positions, kv=kv, start=start)
-
-    x, new_cache = _scan_cached(cfg, x, params["layers"], layer_body, cache)
-    x = apply_norm(x, params["final_norm"], cfg.norm_type)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = x @ head
-    if cfg.logit_softcap:
-        logits = jnp.tanh(logits / cfg.logit_softcap) * cfg.logit_softcap
-    return logits, new_cache
+    x, new_cache = _scan_cached(cfg, x, params["layers"], cache,
+                                _prefill_phase(cfg, positions, start))
+    return _head(params, cfg, x), new_cache
 
 
 def _prefill_by_scan(params: dict, cfg: ModelConfig, tokens, cache, lengths):

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.attention import systolic_attention
 from repro.core.pwl_exp2 import pwl_exp2 as pwl_exp2_jnp
 from repro.kernels.flash_attention.kernel import flash_attention_fwd
 from repro.kernels.flash_attention.ops import flash_attention
@@ -78,7 +79,7 @@ def test_flash_custom_vjp_matches_autodiff_of_ref():
     v = _rand((1, 128, 1, 32), 2)
 
     def f_kernel(q, k, v):
-        return (flash_attention(q, k, v, True) * 0.1).sum()
+        return (systolic_attention(q, k, v, causal=True) * 0.1).sum()
 
     def f_ref(q, k, v):
         return (attention_reference(q, k, v, causal=True) * 0.1).sum()
@@ -144,8 +145,8 @@ def test_flash_bwd_matches_jax_nn_autodiff(causal):
     do = _rand((b, s, h, d), 3)
 
     def f_kernel(q, k, v):
-        o = flash_attention(q, k, v, causal, None, 0, 64, 64, "exact", 8,
-                            "pallas", True)
+        o = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                            interpret=True)
         return (o * do).sum()
 
     def f_xla(q, k, v):
@@ -209,14 +210,14 @@ def test_pallas_bwd_matches_autodiff(case):
 
 
 def test_pallas_custom_vjp_end_to_end():
-    """flash_attention(impl='pallas') trains: full kernel fwd+bwd path."""
+    """flash_attention trains: full kernel fwd+bwd path."""
     q = _rand((1, 128, 2, 32), 0)
     k = _rand((1, 128, 1, 32), 1)
     v = _rand((1, 128, 1, 32), 2)
 
     def loss(q, k, v):
-        o = flash_attention(q, k, v, True, None, 0, 64, 64, "exact", 8,
-                            "pallas", True)
+        o = flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                            interpret=True)
         return (o * o).sum()
 
     g = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
@@ -237,7 +238,7 @@ def test_pallas_path_without_interpret_raises_off_tpu():
         pytest.skip("compiled kernels run on TPU")
     q = _rand((1, 128, 2, 32), 0)
     with pytest.raises(ValueError, match="interpret"):
-        flash_attention(q, q, q, True, None, 0, 64, 64, "exact", 8, "pallas")
+        flash_attention(q, q, q, causal=True, block_q=64, block_k=64)
 
 
 # -- Causal block skip -------------------------------------------------------

@@ -164,6 +164,18 @@ def test_pwl_mode_end_to_end():
     assert bool(jnp.isfinite(loss))
 
 
+@pytest.mark.parametrize("impl", ["naive", "jnp"])
+def test_unknown_attention_impl_raises(impl):
+    """attention_impl is None, "pallas" or "systolic"; any other name fails
+    where attention reads it instead of falling through to the scan."""
+    import dataclasses
+
+    cfg = dataclasses.replace(get_smoke_config("olmo-1b"), attention_impl=impl)
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    with pytest.raises(ValueError, match="attention_impl"):
+        forward(params, cfg, tokens=jnp.zeros((1, 8), jnp.int32))
+
+
 def test_full_configs_match_assignment():
     """The full configs carry the exact assigned hyperparameters."""
     expect = {

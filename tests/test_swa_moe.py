@@ -29,7 +29,7 @@ from repro.core.attention import naive_attention, systolic_attention
 from repro.kernels.flash_attention.kernel import flash_attention_fwd, window_kv_steps
 from repro.kernels.flash_attention.ops import flash_attention
 from repro.models import decode_step, forward, init_cache, init_params, prefill_step
-from repro.models.attention import decode_attention, init_kv_cache
+from repro.models.attention import cached_attention, init_kv_cache
 from repro.models.layers import yarn_frequencies
 from repro.models.moe import moe_forward, moe_params
 
@@ -75,8 +75,8 @@ def test_a_windowed_flash_call_is_not_differentiated():
     q, k, v = _qkv(jax.random.PRNGKey(6), 32, 32)
 
     def loss(q):
-        return flash_attention(q, k, v, True, None, 0, 16, 16, "exact", 8, "pallas",
-                               True, 16).sum()
+        return flash_attention(q, k, v, causal=True, block_q=16, block_k=16,
+                               interpret=True, window=16).sum()
 
     with pytest.raises(NotImplementedError):
         jax.grad(loss)(q)
@@ -175,11 +175,14 @@ def test_a_decode_past_the_window_ignores_older_keys():
                            lengths=jnp.array([pos], jnp.int32))
     x = jax.random.normal(jax.random.PRNGKey(9), (1, 1, cfg.d_model))
     at = jnp.array([[pos]], jnp.int32)
-    base, _ = decode_attention(x, attn, cfg, cache, at)
+    def decode(cache):  # decode is cached attention at the cache's length
+        return cached_attention(x, attn, cfg, cache, at, cache.lengths)[0]
+
+    base = decode(cache)
     old = pos - w  # the newest key the window leaves out
-    moved, _ = decode_attention(x, attn, cfg, cache._replace(k=k0.at[0, old].add(5.0)), at)
+    moved = decode(cache._replace(k=k0.at[0, old].add(5.0)))
     np.testing.assert_array_equal(np.asarray(base), np.asarray(moved))
-    seen, _ = decode_attention(x, attn, cfg, cache._replace(k=k0.at[0, old + 1].add(5.0)), at)
+    seen = decode(cache._replace(k=k0.at[0, old + 1].add(5.0)))
     assert not np.allclose(np.asarray(base), np.asarray(seen))
 
 

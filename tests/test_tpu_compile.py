@@ -98,7 +98,7 @@ CASES = {
     # The training path: custom_vjp forward with LSE, then both grids.
     "train_grad": lambda sh: (
         jax.grad(lambda q, k, v: flash_attention(
-            q, k, v, True, None, 0, 128, 128, "exact", 8, "pallas"
+            q, k, v, causal=True, block_q=128, block_k=128,
         ).astype(jnp.float32).sum(), argnums=(0, 1, 2)),
         _qkv(sh)),
     "pwl_exp2": lambda sh: (
